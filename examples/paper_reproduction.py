@@ -13,6 +13,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import fig1_fullgrad, fig2_stochastic, fig3_grid, \
         table1_rates
     for mod in (fig1_fullgrad, fig2_stochastic, fig3_grid, table1_rates):

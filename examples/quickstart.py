@@ -12,10 +12,12 @@ the stepsize against a single shared schedule in one batched scan.
 import numpy as np
 
 from repro.api import ExperimentSpec, grid, run
+from repro.launch import enable_compile_cache
 from repro.objectives import LogRegProblem, make_synthetic
 
 
 def main():
+    enable_compile_cache()
     n, T = 10, 4000
     A, b = make_synthetic(1.0, 1.0, n=n, m=200, d=300, seed=0)
     prob = LogRegProblem(A, b, lam=0.1)

@@ -7,6 +7,7 @@ Server's sharded, cache-donating jitted step).
 import argparse
 
 from repro.api import ExperimentSpec, ServeJob, ServeBackend
+from repro.launch import enable_compile_cache
 
 
 def main():
@@ -16,6 +17,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = ExperimentSpec(
         objective=ServeJob(arch=args.arch, batch=args.batch,

@@ -16,6 +16,7 @@ import argparse
 import dataclasses
 
 from repro.api import ExperimentSpec, TrainJob, TrainerBackend
+from repro.launch import enable_compile_cache
 from repro import checkpoint
 
 
@@ -47,6 +48,7 @@ def main():
                     help="synchronous baseline (delay_rounds=0)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     job, steps, n_groups = build_job(args.preset)
     if args.sync:

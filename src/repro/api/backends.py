@@ -232,6 +232,34 @@ class TrainerBackend:
                 f"global_batch={job.global_batch}")
         return tr, cfg, n_groups
 
+    def compile_step(self, spec: ExperimentSpec):
+        """The spec's train step compiled from shapes alone, allocating no
+        state: ``memory_analysis()`` sizes a batch before the run, and
+        ``as_text()`` shows the kernels and collectives the run executes.
+        The run itself scans this step, so both hold the same program."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        job = spec.objective
+        if not isinstance(job, TrainJob):
+            raise TypeError("TrainerBackend needs a TrainJob objective")
+        policy: StepsizePolicy = spec.stepsize
+        adaptive = policy.kind == "delay_adaptive"
+        tr, _, n_groups = self._make_trainer(spec, job, policy.gamma,
+                                             adaptive)
+        step = tr.jit_train_step((job.global_batch, job.seq_len),
+                                 with_delay_scale=adaptive)
+        state = jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tr.abstract_state(), tr.state_shardings())
+        repl = NamedSharding(tr.mesh, P())
+        scalars = (jax.ShapeDtypeStruct((), np.float32, sharding=repl),) \
+            if adaptive else ()
+        mask = jax.ShapeDtypeStruct((n_groups,), np.float32, sharding=repl)
+        return step.lower(state, tr.batch_struct(job.global_batch,
+                                                 job.seq_len),
+                          mask, *scalars).compile()
+
     def _run_single(self, spec: ExperimentSpec, job: TrainJob, lr: float,
                     adaptive: bool,
                     metrics_floor: Optional[str] = None) -> RunResult:

@@ -5,6 +5,7 @@ import dataclasses
 import json
 from typing import Any, Optional
 
+import ml_dtypes  # noqa: F401  (registers bfloat16 & co. with numpy)
 import numpy as np
 
 #: payload-format tag for archived results; bump on breaking layout change
@@ -71,11 +72,7 @@ def _from_jsonable(v):
     if isinstance(v, dict):
         if "__ndarray__" in v:
             nd = v["__ndarray__"]
-            try:
-                dt = np.dtype(nd["dtype"])
-            except TypeError:              # e.g. bfloat16 w/o ml_dtypes
-                dt = np.float32
-            return np.asarray(nd["data"], dtype=dt)
+            return np.asarray(nd["data"], dtype=np.dtype(nd["dtype"]))
         if "__array_summary__" in v:
             return v                       # stub stays a stub
         if "__dataclass__" in v:           # restored as a plain field dict

@@ -95,13 +95,14 @@ class AsyncTrainer:
 
     # ------------------------------------------------------------------ specs
     def _pooled_state_specs(self):
-        """Pooled state as Specs: per dtype group one (n_shards, cols) pool
-        each for p (param dtype), m/v (f32) and — when delayed — gbuf."""
+        """Pooled state as Specs: per dtype group one (n_shards, rows, 128)
+        pool each for p (param dtype), m/v (f32) and — when delayed —
+        gbuf."""
         lay = self.pool_layout
 
         def pspec_(dk, dtype):
-            return Spec((lay.n_shards, lay.cols[dk]), (None, None),
-                        "zeros", dtype)
+            return Spec(lay.pool_shape(dk), (None, None, None), "zeros",
+                        dtype)
 
         pools = {}
         for dk in lay.groups:

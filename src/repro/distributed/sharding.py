@@ -204,22 +204,22 @@ def pool_axes(mesh: Mesh, rules: Rules = DEFAULT_RULES) -> tuple:
 
 
 def pool_shard_count(mesh: Mesh, rules: Rules = DEFAULT_RULES) -> int:
-    """Row count of the pooled ``(n_shards, cols)`` buffers: one row per
-    ZeRO shard (1 on data-parallel-free meshes)."""
+    """Leading dim of the pooled ``(n_shards, rows, 128)`` buffers: one
+    shard per ZeRO shard (1 on data-parallel-free meshes)."""
     return int(np.prod([mesh.shape[a] for a in pool_axes(mesh, rules)],
                        dtype=int)) or 1
 
 
 def pooled_pspec(mesh: Mesh, rules: Rules = DEFAULT_RULES) -> P:
-    """PartitionSpec of a pooled ``(n_shards, cols)`` state buffer: rows
-    over the data axes (each device owns its ZeRO shard of EVERY leaf),
-    columns unsharded.  Replicated over the model axis — pooling trades the
-    per-leaf 2D model×data sharding for O(n_dtypes) kernel launches; see
-    the README for when to pick which."""
+    """PartitionSpec of a pooled ``(n_shards, rows, 128)`` state buffer:
+    shards over the data axes (each device owns its ZeRO shard of EVERY
+    leaf), rows and lanes unsharded.  Replicated over the model axis —
+    pooling trades the per-leaf 2D model×data sharding for O(n_dtypes)
+    kernel launches; see the README for when to pick which."""
     axes = pool_axes(mesh, rules)
     if not axes:
-        return P(None, None)
-    return P(axes if len(axes) > 1 else axes[0], None)
+        return P(None, None, None)
+    return P(axes if len(axes) > 1 else axes[0], None, None)
 
 
 def tree_pspecs(spec_tree, mesh: Mesh, rules: Rules = DEFAULT_RULES,

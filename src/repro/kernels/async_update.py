@@ -19,7 +19,11 @@ whole update into ONE pass per tile:
 Tiling: flat parameter tensors are viewed as (rows, LANE) with LANE=128
 (the TPU lane width); BlockSpec tiles (block_rows, 128) keep each operand
 slab in VMEM.  Scalars (lr·scales, bias corrections) arrive via a small
-SMEM block, the standard scalar-plumbing pattern.
+SMEM block, the standard scalar-plumbing pattern.  A tensor whose size is
+already a whole number of tiles and whose minor dim is LANE (the pooled
+layout of :mod:`repro.optim.pool`) is viewed without a copy, and every
+state operand is aliased to its output (``input_output_aliases``), so a
+donated state is updated in place.
 
 Validated under interpret=True against ``ref.reference_async_update`` /
 ``ref.reference_fused_adam``.
@@ -34,6 +38,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
+#: default tile height: one grid step streams (BLOCK_ROWS, LANE) slabs
+BLOCK_ROWS = 256
 F32 = jnp.float32
 
 
@@ -48,6 +54,12 @@ def _pad_to_tiles(x, block_rows):
     return flat.reshape(tiles * block_rows, LANE), tiles
 
 
+def _swap_alias(gbuf2, fresh_dtype, i_in: int, i_out: int) -> dict:
+    """The gbuf → gbuf' alias, when the buffer already holds the fresh
+    gradient's dtype (an alias must keep shape and dtype)."""
+    return {i_in: i_out} if gbuf2.dtype == jnp.dtype(fresh_dtype) else {}
+
+
 def _async_update_kernel(scal_ref, p_ref, gbuf_ref, g_ref, p_out, gbuf_out):
     eff = scal_ref[0]
     p = p_ref[...]
@@ -57,7 +69,8 @@ def _async_update_kernel(scal_ref, p_ref, gbuf_ref, g_ref, p_out, gbuf_out):
 
 
 def async_update_pallas(params, gbuf, grads, *, lr, clip_scale=1.0,
-                        delay_scale=1.0, block_rows=256, interpret=False):
+                        delay_scale=1.0, block_rows=BLOCK_ROWS,
+                        interpret=False):
     """Fused delayed-gradient apply on one flat tensor.
 
     params/gbuf/grads: same shape & dtype.  Returns (p', gbuf')."""
@@ -85,6 +98,7 @@ def async_update_pallas(params, gbuf, grads, *, lr, clip_scale=1.0,
             jax.ShapeDtypeStruct(p2.shape, dtype),
             jax.ShapeDtypeStruct(b2.shape, grads.dtype),
         ],
+        input_output_aliases={1: 0, **_swap_alias(b2, grads.dtype, 2, 1)},
         interpret=interpret,
     )(eff, p2, b2, g2)
     n = params.size
@@ -99,7 +113,7 @@ def _sgd_step_kernel(scal_ref, p_ref, g_ref, p_out):
 
 
 def sgd_step_pallas(params, grads, *, lr, clip_scale=1.0, delay_scale=1.0,
-                    block_rows=256, interpret=False):
+                    block_rows=BLOCK_ROWS, interpret=False):
     """Plain fused SGD step on one flat tensor: p' = p − eff·g, no buffer.
 
     The swap-free sibling of ``async_update`` for the NON-delayed path —
@@ -121,6 +135,7 @@ def sgd_step_pallas(params, grads, *, lr, clip_scale=1.0, delay_scale=1.0,
         ],
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(p2.shape, dtype),
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(eff, p2, g2)
     return p_new.ravel()[:params.size].reshape(shape)
@@ -136,8 +151,8 @@ def _sgd_momentum_kernel(scal_ref, p_ref, m_ref, g_ref, p_out, m_out,
 
 
 def sgd_momentum_step_pallas(params, m, grads, *, lr, momentum,
-                             clip_scale=1.0, delay_scale=1.0, block_rows=256,
-                             interpret=False):
+                             clip_scale=1.0, delay_scale=1.0,
+                             block_rows=BLOCK_ROWS, interpret=False):
     """Fused heavy-ball SGD on one flat tensor: m' = μ·m + clip·g,
     p' = p − lr·delay_scale·m'.  m is f32.  Returns (p', m')."""
     assert params.shape == grads.shape == m.shape
@@ -166,6 +181,7 @@ def sgd_momentum_step_pallas(params, m, grads, *, lr, momentum,
             jax.ShapeDtypeStruct(p2.shape, dtype),
             jax.ShapeDtypeStruct(m2.shape, F32),
         ],
+        input_output_aliases={1: 0, 2: 1},
         interpret=interpret,
     )(scal, p2, m2, g2)
     n = params.size
@@ -185,7 +201,7 @@ def _sgd_momentum_delayed_kernel(scal_ref, p_ref, m_ref, gb_ref, g_ref,
 
 def sgd_momentum_delayed_pallas(params, m, gbuf, grads, *, lr, momentum,
                                 clip_scale=1.0, delay_scale=1.0,
-                                block_rows=256, interpret=False):
+                                block_rows=BLOCK_ROWS, interpret=False):
     """Delayed-buffer heavy-ball SGD, one HBM pass per tile:
 
         m'    ← μ·m + clip·gbuf        (momentum on the STALE gradient)
@@ -223,6 +239,8 @@ def sgd_momentum_delayed_pallas(params, m, gbuf, grads, *, lr, momentum,
             jax.ShapeDtypeStruct(m2.shape, F32),
             jax.ShapeDtypeStruct(b2.shape, grads.dtype),
         ],
+        input_output_aliases={1: 0, 2: 1,
+                              **_swap_alias(b2, grads.dtype, 3, 2)},
         interpret=interpret,
     )(scal, p2, m2, b2, g2)
     n = params.size
@@ -258,7 +276,7 @@ def _fused_adam_kernel(scal_ref, p_ref, m_ref, v_ref, g_ref,
 
 def fused_adam_pallas(p, m, v, g, *, lr, beta1=0.9, beta2=0.95, eps=1e-8,
                       count=1, clip_scale=1.0, weight_decay=0.0,
-                      block_rows=256, interpret=False):
+                      block_rows=BLOCK_ROWS, interpret=False):
     """One fused Adam step on a flat tensor; m/v f32.  Returns (p', m', v').
 
     ``clip_scale`` is the global-norm clip factor (the norm itself is a tree
@@ -293,6 +311,7 @@ def fused_adam_pallas(p, m, v, g, *, lr, beta1=0.9, beta2=0.95, eps=1e-8,
             jax.ShapeDtypeStruct(m2.shape, F32),
             jax.ShapeDtypeStruct(v2.shape, F32),
         ],
+        input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
     )(scal, p2, m2, v2, g2)
     n = p.size
@@ -323,7 +342,7 @@ def _fused_adam_delayed_kernel(scal_ref, p_ref, m_ref, v_ref, gb_ref, g_ref,
 
 def fused_adam_delayed_pallas(p, m, v, gbuf, g, *, lr, beta1=0.9, beta2=0.95,
                               eps=1e-8, count=1, clip_scale=1.0,
-                              weight_decay=0.0, block_rows=256,
+                              weight_decay=0.0, block_rows=BLOCK_ROWS,
                               interpret=False):
     """Delayed-buffer Adam step, one HBM pass per tile:
 
@@ -368,6 +387,8 @@ def fused_adam_delayed_pallas(p, m, v, gbuf, g, *, lr, beta1=0.9, beta2=0.95,
             jax.ShapeDtypeStruct(v2.shape, F32),
             jax.ShapeDtypeStruct(b2.shape, g.dtype),
         ],
+        input_output_aliases={1: 0, 2: 1, 3: 2,
+                              **_swap_alias(b2, g.dtype, 4, 3)},
         interpret=interpret,
     )(scal, p2, m2, v2, b2, g2)
     n = p.size

@@ -53,10 +53,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(relevant)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = q @ k.T                                            # (bq, bk)
+        q = q_ref[...].astype(jnp.float32) * scale             # (bq, d)
+        k = k_ref[...].astype(jnp.float32)                     # (bk, d)
+        v = v_ref[...].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bk)
         qp = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         kp = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         ok = (qp < seq_q) & (kp < seq_k)
@@ -66,26 +66,30 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             ok &= kp > qp - window
         s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...]                                    # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
+        p = jnp.exp(s - m_cur)
         p = jnp.where(ok, p, 0.0)          # NEG_INF rows would exp→~0 anyway
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + p @ v
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + p @ v
         m_scr[...] = m_cur
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
         l = l_scr[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / safe).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=None,
                            block_q=512, block_k=512, interpret=False):
     """q: (B, Sq, H, D); k/v: (B, Sk, KV, D) with H % KV == 0.
-    Returns (B, Sq, H, D) in q.dtype."""
+    Returns (B, Sq, H, D) in q.dtype.
+
+    The kernel runs head-major: operands are transposed to (B, H, S, D) so
+    every block's last two dims are (block, D) — the (8, 128) tiling rule
+    of the TPU lowering refuses a block of 1 on the heads axis."""
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     assert H % KV == 0
@@ -102,29 +106,28 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
     if Sk_pad != Sk:
         k = jnp.pad(k, ((0, 0), (0, Sk_pad - Sk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, Sk_pad - Sk), (0, 0), (0, 0)))
+    q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))    # (B, heads, S, D)
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
         n_kv_blocks=nk, causal=causal, window=window, seq_q=Sq, seq_k=Sk)
+    sq = pl.Squeezed()
+    q_spec = pl.BlockSpec((sq, sq, block_q, D),
+                          lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((sq, sq, block_k, D),
+                           lambda b, h, qi, ki, g=group: (b, h // g, ki, 0))
 
     out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, qi, ki, g=group: (b, ki, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, qi, ki, g=group: (b, ki, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_pad, H, D), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_pad, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq]
+    return jnp.swapaxes(out, 1, 2)[:, :Sq]

@@ -1,13 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute under ``interpret=True`` (pallas
-interpreter) — set ``REPRO_KERNEL_INTERPRET=0`` on a real TPU to compile
-them.  Each wrapper falls back to the pure-jnp oracle (`ref.py`) when
+``interpret=None`` follows the platform alone: compiled Mosaic kernels on
+a TPU backend, the Pallas interpreter anywhere else (the CPU test path).
+Each wrapper falls back to the pure-jnp oracle (`ref.py`) when
 ``use_kernel=False``, which is also what the model code uses on CPU.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -21,9 +20,6 @@ from .ssd_chunk import ssd_chunk_pallas
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

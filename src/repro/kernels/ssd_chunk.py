@@ -12,8 +12,11 @@ This kernel fuses, per (batch, chunk, head-block) grid cell:
 so the (c, c) decay/score matrices never touch HBM.  The inter-chunk scan
 (S/c steps) stays in jnp — it is tiny and sequential.
 
-Grid: (B, n_chunks, H).  Blocks: x (c, P), dt (c,), B/C (c, N) in VMEM;
-c=chunk (default 128) and P, N are MXU-friendly multiples of 64/128.
+Grid: (B·n_chunks, H).  The kernel runs head-major: x is laid out
+(B·nc, H, c, P) so its blocks are (c, P) slabs, B/C are (c, N), and the
+per-head log-decay prefix sums arrive as a (c, 1) column and a (1, c) row
+(computed in the wrapper) — every block's last two dims are whole array
+dims, as the TPU lowering's (8, 128) tiling rule requires.
 
 Validated under interpret=True against ``ref.reference_ssd_chunk``.
 """
@@ -28,29 +31,28 @@ from jax.experimental import pallas as pl
 F32 = jnp.float32
 
 
-def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, *,
-                      chunk):
-    x = x_ref[0, :, 0, :].astype(F32)          # (c, P)
-    dt = dt_ref[0, :, 0].astype(F32)           # (c,)
-    A = a_ref[0]                               # scalar decay rate (this head)
-    Bm = b_ref[0, :, :].astype(F32)            # (c, N)
-    Cm = c_ref[0, :, :].astype(F32)            # (c, N)
+def _ssd_chunk_kernel(x_ref, dt_ref, cc_ref, cr_ref, b_ref, c_ref,
+                      y_ref, st_ref, *, chunk):
+    x = x_ref[...].astype(F32)                 # (c, P)
+    dt = dt_ref[...]                           # (c, 1)
+    cum_c = cc_ref[...]                        # (c, 1) Σ dt·A up to row i
+    cum_r = cr_ref[...]                        # (1, c) the same, as a row
+    Bm = b_ref[...].astype(F32)                # (c, N)
+    Cm = c_ref[...].astype(F32)                # (c, N)
 
-    la = dt * A                                # (c,) log-decays
-    cum = jnp.cumsum(la)                       # (c,)
-    # segsum matrix: cum[i] − cum[j] for j ≤ i else −inf
-    diff = cum[:, None] - cum[None, :]
+    # segsum matrix: cum[i] − cum[j] for j ≤ i, masked to 0 above
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    L = jnp.where(jj <= ii, jnp.exp(cum_c - cum_r), 0.0)
 
-    xdt = x * dt[:, None]                      # (c, P)
-    scores = (Cm @ Bm.T) * L                   # (c, c)
-    y_ref[0, :, 0, :] = (scores @ xdt).astype(y_ref.dtype)
+    xdt = x * dt                               # (c, P)
+    cbt = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # C Bᵀ
+    y_ref[...] = ((cbt * L) @ xdt).astype(y_ref.dtype)
 
-    decay_to_end = jnp.exp(cum[-1] - cum)      # (c,)
-    st = (Bm * decay_to_end[:, None]).T @ xdt  # (N, P)
-    st_ref[0, 0, :, :] = st.astype(st_ref.dtype)
+    decay_to_end = jnp.exp(cum_c[chunk - 1:, :] - cum_c)          # (c, 1)
+    st = jax.lax.dot_general(Bm * decay_to_end, xdt,
+                             (((0,), (0,)), ((), ())))            # (N, P)
+    st_ref[...] = st.astype(st_ref.dtype)
 
 
 def ssd_chunk_pallas(x, dt, A, B_, C_, *, interpret=False):
@@ -63,33 +65,38 @@ def ssd_chunk_pallas(x, dt, A, B_, C_, *, interpret=False):
     """
     Bb, nc, c, H, P = x.shape
     N = B_.shape[-1]
+    G = Bb * nc
 
     kern = functools.partial(_ssd_chunk_kernel, chunk=c)
-    grid = (Bb * nc, H)
-    xr = x.reshape(Bb * nc, c, H, P)
-    dtr = dt.reshape(Bb * nc, c, H)
-    br = B_.reshape(Bb * nc, c, N)
-    cr = C_.reshape(Bb * nc, c, N)
+    xr = jnp.swapaxes(x.reshape(G, c, H, P), 1, 2)             # (G, H, c, P)
+    dtr = jnp.swapaxes(dt.reshape(G, c, H).astype(F32), 1, 2)  # (G, H, c)
+    cum = jnp.cumsum(dtr * A.astype(F32)[None, :, None], axis=-1)
+    br = B_.reshape(G, c, N)
+    cr = C_.reshape(G, c, N)
 
+    sq = pl.Squeezed()
+    col = pl.BlockSpec((sq, sq, c, 1), lambda g, h: (g, h, 0, 0))
+    bc = pl.BlockSpec((sq, c, N), lambda g, h: (g, 0, 0))
     y, st = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(G, H),
         in_specs=[
-            pl.BlockSpec((1, c, 1, P), lambda g, h: (g, 0, h, 0)),
-            pl.BlockSpec((1, c, 1), lambda g, h: (g, 0, h)),
-            pl.BlockSpec((1,), lambda g, h: (h,)),
-            pl.BlockSpec((1, c, N), lambda g, h: (g, 0, 0)),
-            pl.BlockSpec((1, c, N), lambda g, h: (g, 0, 0)),
+            pl.BlockSpec((sq, sq, c, P), lambda g, h: (g, h, 0, 0)),
+            col,
+            col,
+            pl.BlockSpec((sq, sq, 1, c), lambda g, h: (g, h, 0, 0)),
+            bc,
+            bc,
         ],
         out_specs=[
-            pl.BlockSpec((1, c, 1, P), lambda g, h: (g, 0, h, 0)),
-            pl.BlockSpec((1, 1, N, P), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((sq, sq, c, P), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((sq, sq, N, P), lambda g, h: (g, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bb * nc, c, H, P), x.dtype),
-            jax.ShapeDtypeStruct((Bb * nc, H, N, P), F32),
+            jax.ShapeDtypeStruct((G, H, c, P), x.dtype),
+            jax.ShapeDtypeStruct((G, H, N, P), F32),
         ],
         interpret=interpret,
-    )(xr, dtr, A.astype(F32), br, cr)
-    return (y.reshape(Bb, nc, c, H, P),
+    )(xr, dtr[..., None], cum[..., None], cum[:, :, None, :], br, cr)
+    return (jnp.swapaxes(y, 1, 2).reshape(Bb, nc, c, H, P),
             st.reshape(Bb, nc, H, N, P))

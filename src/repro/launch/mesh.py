@@ -14,13 +14,8 @@ ICI_BW = 50e9                 # B/s per link
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (>= 0.5), plain make_mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,9 +24,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_host_mesh():
-    """Whatever this host has (1 device on CPU) as (data=1, model=n)."""
-    return _make_mesh((1, len(jax.devices())), ("data", "model"))
+def make_host_mesh(data: int = 1):
+    """Whatever this host has (1 device on CPU) as (data, model=n/data)."""
+    n = len(jax.devices())
+    if data < 1 or n % data:
+        raise ValueError(f"data={data} must divide the {n} devices")
+    return _make_mesh((data, n // data), ("data", "model"))
 
 
 def mesh_devices(mesh) -> int:

@@ -120,8 +120,10 @@ def main():
     from ..distributed import DEFAULT_RULES, auto_rules
     from ..models import n_params
     from .. import checkpoint
+    from .cache import enable_compile_cache
     from .mesh import make_production_mesh, make_host_mesh
 
+    enable_compile_cache()
     mesh = make_host_mesh() if args.host_mesh else \
         make_production_mesh(multi_pod=args.multi_pod)
     job = TrainJob(

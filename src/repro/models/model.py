@@ -423,17 +423,25 @@ def loss_fn(cfg: ArchConfig, params, batch, example_weights=None,
 
 def _ring_from_seq(k_seq, v_seq, W: int):
     """(L,B,S,KV,D) stacked per-layer k/v → ring cache of the last W tokens,
-    placed at slot = pos mod W, plus the positions buffer."""
+    placed at slot = pos mod W, plus the positions buffer.
+
+    The slots are static, so the ring is a pad (S < W) or a rotation
+    (S ≥ W) of the last W tokens: no scatter.  Two sibling scatters into
+    zeros here crash the TPU compiler's scatter fusion."""
     S = k_seq.shape[2]
     take = min(W, S)
-    pos = jnp.arange(S - take, S)
-    slots = jnp.mod(pos, W)
-    kc = jnp.zeros(k_seq.shape[:2] + (W,) + k_seq.shape[3:], k_seq.dtype)
-    vc = jnp.zeros_like(kc)
-    kc = kc.at[:, :, slots].set(k_seq[:, :, -take:])
-    vc = vc.at[:, :, slots].set(v_seq[:, :, -take:])
-    positions = jnp.full((W,), -1, jnp.int32).at[slots].set(pos.astype(jnp.int32))
-    return kc, vc, positions
+    pos = np.arange(S - take, S)
+    positions = np.full((W,), -1, np.int32)
+    positions[pos % W] = pos
+
+    def ring(x):
+        x = x[:, :, S - take:]
+        if take < W:                      # slots 0..S-1, the rest empty
+            return jnp.pad(x, ((0, 0), (0, 0), (0, W - take))
+                           + ((0, 0),) * (x.ndim - 3))
+        return jnp.roll(x, S % W, axis=2)
+
+    return ring(k_seq), ring(v_seq), jnp.asarray(positions)
 
 
 def prefill(cfg: ArchConfig, params, batch, ctx_len: Optional[int] = None):

@@ -10,15 +10,20 @@ Adam/SGD(+momentum) step, bias corrections, weight decay, delay_scale and
 the gbuf ← fresh-grads swap — executes as one ``pallas_call`` per dtype
 pool, O(n_dtypes) launches instead of O(n_leaves).
 
-Layout.  A pool is a ``(n_shards, cols)`` buffer: leaf ``l`` (padded to
+Layout.  A pool is a ``(n_shards, rows, 128)`` buffer, read as ``n_shards``
+flat shards of ``cols = rows · 128`` columns: leaf ``l`` (padded to
 ``n_shards · width_l`` elements and chunked row-major) owns the column band
-``[col_l, col_l + width_l)`` of every row, so row ``r`` holds shard ``r`` of
-EVERY leaf.  Sharding the pool ``P(data_axes, None)`` therefore gives each
-ZeRO shard a contiguous, self-contained slice of the whole state: the fused
-update runs under ``shard_map`` over the mesh's data axes with zero
-XLA-inserted gathers, and leaves that were too small or indivisible to
-ZeRO-shard individually are sharded anyway (padding is per-leaf, ≤
-``n_shards − 1`` elements).
+``[col_l, col_l + width_l)`` of every shard, so shard ``r`` holds shard
+``r`` of EVERY leaf.  Sharding the pool ``P(data_axes, None, None)``
+therefore gives each ZeRO shard a contiguous, self-contained slice of the
+whole state: the fused update runs under ``shard_map`` over the mesh's data
+axes with zero XLA-inserted gathers, and leaves that were too small or
+indivisible to ZeRO-shard individually are sharded anyway.  Widths round up
+to whole 128-lane rows and ``cols`` to whole kernel tiles
+(``BLOCK_ROWS × 128``), so the kernels view each local shard as
+``(rows, 128)`` tiles without a copy, and the lane-minor shape costs no
+layout padding on a TPU (a ``(1, cols)`` bf16 buffer would be stored twice
+over).
 
 Padding invariant.  :func:`pool_tree` zero-fills pad columns and every
 kernel preserves zeros there (moments start at 0, weight decay multiplies a
@@ -38,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.async_update import BLOCK_ROWS, LANE
 from .optimizers import OptConfig, clip_scale_from_norm
 
 F32 = jnp.float32
@@ -55,18 +61,19 @@ class LeafSlot:
     path: str           # keystr (debugging / error messages)
     shape: tuple
     dtype: str          # dtype key of the POOL group (the param dtype)
-    col: int            # first column in the (n_shards, cols) pool
-    width: int          # columns owned = ceil(size / n_shards)
+    col: int            # first column of every flat shard (a 128 multiple)
+    width: int          # columns owned: ceil(size / n_shards), 128-rounded
     size: int
 
 
 @dataclasses.dataclass(frozen=True)
 class PoolLayout:
-    """tree ↔ per-dtype ``(n_shards, cols)`` pool buffers, built once.
+    """tree ↔ per-dtype ``(n_shards, rows, 128)`` pool buffers, built once.
 
     ``groups`` maps a dtype key ("bfloat16", "float32", ...) to the slots of
     every leaf with that dtype, in tree-flatten order; ``cols`` is each
-    group's total column count.  The same layout serves params, grads and
+    group's total column count per shard (``rows · 128``, a whole number of
+    kernel tiles).  The same layout serves params, grads and
     the f32 moments (moments pool under the PARAM's group so the kernel
     reads aligned bands, see ``pool_tree(dtype=...)``)."""
 
@@ -79,6 +86,9 @@ class PoolLayout:
     @property
     def n_pools(self) -> int:
         return len(self.groups)
+
+    def pool_shape(self, dk: str) -> tuple:
+        return (self.n_shards, self.cols[dk] // LANE, LANE)
 
 
 def build_layout(tree, n_shards: int = 1) -> PoolLayout:
@@ -93,15 +103,20 @@ def build_layout(tree, n_shards: int = 1) -> PoolLayout:
     for index, (path, leaf) in enumerate(leaves_p):
         dk = _dtype_key(leaf.dtype)
         size = int(np.prod(leaf.shape)) if len(leaf.shape) else 1
-        width = -(-size // n_shards)          # ceil
+        width = _round_up(-(-size // n_shards), LANE)
         slot = LeafSlot(index=index, path=jax.tree_util.keystr(path),
                         shape=tuple(leaf.shape), dtype=dk,
                         col=cols.get(dk, 0), width=width, size=size)
         groups.setdefault(dk, []).append(slot)
         cols[dk] = slot.col + width
+    cols = {dk: _round_up(c, BLOCK_ROWS * LANE) for dk, c in cols.items()}
     return PoolLayout(n_shards=n_shards,
                       groups={k: tuple(v) for k, v in groups.items()},
                       cols=cols, treedef=treedef, n_leaves=len(leaves_p))
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _constrain(x, sharding):
@@ -110,7 +125,7 @@ def _constrain(x, sharding):
 
 
 def pool_tree(layout: PoolLayout, tree, dtype=None, sharding=None) -> dict:
-    """tree → {dtype key: (n_shards, cols) pool}.
+    """tree → {dtype key: (n_shards, rows, 128) pool}.
 
     ``dtype`` overrides the pool element type (f32 moments pooling under
     their param's group); ``sharding`` (a NamedSharding) is applied to every
@@ -130,7 +145,11 @@ def pool_tree(layout: PoolLayout, tree, dtype=None, sharding=None) -> dict:
             pad = n * s.width - s.size
             if pad:
                 flat = jnp.pad(flat, (0, pad))
-            blocks.append(flat.reshape(n, s.width))
+            blocks.append(flat.reshape(n, s.width // LANE, LANE))
+        tail = layout.cols[dk] - (slots[-1].col + slots[-1].width)
+        if tail:
+            blocks.append(jnp.zeros((n, tail // LANE, LANE),
+                                    blocks[0].dtype))
         pools[dk] = _constrain(jnp.concatenate(blocks, axis=1)
                                if len(blocks) > 1 else blocks[0], sharding)
     return pools
@@ -144,7 +163,8 @@ def unpool_tree(layout: PoolLayout, pools: dict, shardings=None):
     for dk, slots in layout.groups.items():
         pool = pools[dk]
         for s in slots:
-            flat = pool[:, s.col:s.col + s.width].reshape(-1)
+            rows = pool[:, s.col // LANE:(s.col + s.width) // LANE]
+            flat = rows.reshape(-1)
             leaves[s.index] = flat[:s.size].reshape(s.shape)
     tree = jax.tree_util.tree_unflatten(layout.treedef, leaves)
     if shardings is not None:
@@ -155,7 +175,7 @@ def unpool_tree(layout: PoolLayout, pools: dict, shardings=None):
 def pool_zeros(layout: PoolLayout, dtype=None, sharding=None) -> dict:
     """Zero pools (moments / delayed buffer init)."""
     return {dk: _constrain(
-        jnp.zeros((layout.n_shards, layout.cols[dk]),
+        jnp.zeros(layout.pool_shape(dk),
                   jnp.dtype(dtype) if dtype is not None else jnp.dtype(dk)),
         sharding) for dk in layout.groups}
 
@@ -193,28 +213,28 @@ def _maybe_shard_map(fn, mesh, axes, n_pool_args, n_scalar_args, n_out):
     ``mesh=None`` or no data axes → plain call."""
     if mesh is None or not axes:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    spec = P(axes if len(axes) > 1 else axes[0], None)
+    spec = P(axes if len(axes) > 1 else axes[0], None, None)
     in_specs = (spec,) * n_pool_args + (P(),) * n_scalar_args
     out_specs = (spec,) * n_out if n_out > 1 else spec
-    # check_rep=False: pallas_call carries no replication rule
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    # check_vma=False: pallas_call carries no replication rule
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _block_rows(n_elems: int, interpret: bool) -> int:
     """Tile height for a pooled kernel call.
 
-    Compiled mode keeps the kernels' default VMEM-sized pipeline tiles.
-    Interpret mode emulates the grid SEQUENTIALLY with whole-array
-    functional updates — cost O(grid_points · pool_size), quadratic for one
-    big pool split into many tiles — so there the whole pool is ONE tile
-    (grid=1, linear, and exactly what the launch-count story promises)."""
+    Compiled mode keeps the kernels' default VMEM-sized pipeline tiles
+    (the layout pads every pool to a whole number of them).  Interpret
+    mode emulates the grid SEQUENTIALLY with whole-array functional updates
+    — cost O(grid_points · pool_size), quadratic for one big pool split
+    into many tiles — so there the whole pool is ONE tile (grid=1, linear,
+    and exactly what the launch-count story promises)."""
     if not interpret:
-        return 256
-    return max(1, -(-n_elems // 128))
+        return BLOCK_ROWS
+    return max(1, -(-n_elems // LANE))
 
 
 def _adam_group_fns(cfg: OptConfig, interpret: bool, delayed: bool):

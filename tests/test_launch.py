@@ -4,9 +4,12 @@ import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
+import jax
 import pytest
 
+from repro.launch import enable_compile_cache
 from repro.launch.hlo_cost import analyze, parse_module, _split_instr
 
 HLO = """\
@@ -104,3 +107,22 @@ def test_roofline_analysis_on_existing_records():
         assert "error" not in r, r
         assert r["compute_s"] > 0 and r["memory_s"] > 0
         assert r["dominant"] in ("compute", "memory", "collective")
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compile_cache_leaves_the_environment_setting_to_jax(monkeypatch,
+                                                            tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before

@@ -95,6 +95,14 @@ def test_resolve_update_impl_falls_back_off_tpu():
         resolve_update_impl("cuda")
 
 
+@pytest.mark.parametrize("impl", ["pallas", "pallas_pooled"])
+def test_resolve_update_impl_keeps_compiled_on_tpu(monkeypatch, impl):
+    """On a TPU backend a compiled request never resolves to the
+    interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_update_impl(impl) == impl
+
+
 def test_make_optimizer_rejects_unknown_impl():
     with pytest.raises(ValueError):
         make_optimizer(OptConfig(update_impl="fast"))

@@ -1,7 +1,7 @@
 """Pooled-state fused update (repro.optim.pool) — single-device suite.
 
 The pooled impl changes the optimizer-state MEMORY LAYOUT (per-dtype
-(n_shards, cols) pool buffers, built once) and the launch count (one
+(n_shards, rows, 128) pool buffers, built once) and the launch count (one
 pallas_call per dtype pool instead of one per leaf); the numbers must not
 change.  Parity bounds follow tests/test_optim_fused.py: pure copies and
 counts bitwise, f32 math within FMA-contraction rounding, bf16 at bf16
@@ -82,7 +82,8 @@ def test_layout_roundtrip_bitwise(n_shards):
     assert lay.n_leaves == len(tree)
     pools = pool_tree(lay, tree)
     for dk, pool in pools.items():
-        assert pool.shape == (n_shards, lay.cols[dk])
+        assert pool.shape == (n_shards, lay.cols[dk] // 128, 128)
+        assert lay.cols[dk] % (256 * 128) == 0  # whole compiled-kernel tiles
         assert str(pool.dtype) == dk
     back = unpool_tree(lay, pools)
     for k in tree:
@@ -99,7 +100,7 @@ def test_pool_f32_override_groups_by_param_dtype():
     assert set(pools) == set(lay.groups)
     for dk, pool in pools.items():
         assert pool.dtype == F32
-        assert pool.shape == (4, lay.cols[dk])
+        assert pool.shape == (4, lay.cols[dk] // 128, 128)
 
 
 def test_pooled_global_norm_matches_tree_norm():
@@ -306,7 +307,7 @@ def test_async_trainer_pooled_state_structure():
     assert set(state) == {"pools", "opt", "step"}
     for dk, grp in state["pools"].items():
         assert set(grp) == {"p", "m", "v", "gbuf"}
-        assert grp["p"].shape == (lay.n_shards, lay.cols[dk])
+        assert grp["p"].shape == (lay.n_shards, lay.cols[dk] // 128, 128)
         assert grp["m"].dtype == jnp.float32
     # abstract/sharding trees mirror the concrete state
     ab = tr.abstract_state()
