@@ -18,7 +18,6 @@ All three return a :class:`RunResult`.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 from typing import Callable, Optional, Protocol, runtime_checkable
 
 import jax
@@ -26,6 +25,7 @@ import numpy as np
 
 from ..core import delay_adaptive_stepsizes, replay, replay_grid, round_masks
 from ..core.trace import summarize
+from ..obs import span
 from ..runtime import compile_plan, execute
 from .result import RunResult
 from .spec import ExperimentSpec, ServeJob, StepsizePolicy, TrainJob
@@ -447,16 +447,14 @@ class ServeBackend:
                                                seed=spec.seed), rules=rules)
         prompts = np.random.default_rng(spec.seed).integers(
             0, cfg.vocab, (job.batch, job.prompt_len)).astype(np.int32)
-        with (rec.span("prefill", "server", batch=job.batch,
-                       plen=job.prompt_len)
-              if rec is not None else nullcontext()):
+        with span(rec, "prefill", "server", batch=job.batch,
+                  plen=job.prompt_len):
             last, cache = prefill(cfg, params,
                                   {"tokens": jnp.asarray(prompts)},
                                   ctx_len=ctx)
             toks = jnp.argmax(last, axis=-1).astype(jnp.int32)
         t_dec = time.time()
-        with (rec.span("decode", "server", steps=spec.T - 1)
-              if rec is not None else nullcontext()):
+        with span(rec, "decode", "server", steps=spec.T - 1):
             gen = server.generate(params, np.asarray(toks), spec.T - 1,
                                   start_pos=job.prompt_len, cache=cache)
         gen = np.concatenate([np.asarray(toks)[:, None], gen], axis=1)

@@ -34,6 +34,7 @@ import shutil
 from collections import deque
 from typing import Optional
 
+from ..obs import span
 from . import checkpointer
 
 _ROUND_DIR = re.compile(r"^round-(\d{8})$")
@@ -94,12 +95,9 @@ class AsyncSnapshotter:
             # next chunk donating the carry cannot clobber the snapshot
             self._copy_jit = jax.jit(
                 lambda s: jax.tree_util.tree_map(jnp.copy, s))
-        rec = self.recorder
-        if rec is None:
+        with span(self.recorder, "snapshot_copy", "snapshot",
+                  round=int(round_i)):
             snap = self._copy_jit(state)
-        else:
-            with rec.span("snapshot_copy", "snapshot", round=int(round_i)):
-                snap = self._copy_jit(state)
         for leaf in jax.tree_util.tree_leaves(snap):
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
@@ -122,14 +120,12 @@ class AsyncSnapshotter:
         r, snap, extra = self._pending.popleft()
         rec = self.recorder
         meta = {**self._meta, **extra, "round": r, "kind": "snapshot"}
-        if rec is None:
+        # in the trace this span sits a whole cadence AFTER the
+        # snapshot_offer/snapshot_copy of the same round — the visible
+        # proof the two-deep async window overlaps compute
+        with span(rec, "snapshot_finalise", "snapshot", round=r):
             checkpointer.save(self.round_dir(r), snap, step=r, meta=meta)
-        else:
-            # in the trace this span sits a whole cadence AFTER the
-            # snapshot_offer/snapshot_copy of the same round — the
-            # visible proof the two-deep async window overlaps compute
-            with rec.span("snapshot_finalise", "snapshot", round=r):
-                checkpointer.save(self.round_dir(r), snap, step=r, meta=meta)
+        if rec is not None:
             rec.count("snapshot_writes")
         self._written.append((r, self.round_dir(r)))
         self._prune()

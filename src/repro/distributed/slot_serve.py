@@ -76,7 +76,6 @@ fresh closure per call would silently recompile every run), asserted by
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
 from typing import Callable, Optional, Union
 
 import jax
@@ -86,15 +85,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..models import model as M
-from ..obs import CompileWatch
+from ..obs import CompileWatch, span
 from .admission import AdmissionPolicy, AdmissionTrace, parse_admission
 from .sharding import Rules, DEFAULT_RULES, sharded_trace, tree_shardings
-
-
-def _span(rec, name, lane, **args):
-    """Optional-recorder span (no-op without one — un-observed serves
-    pay nothing on the dispatch path)."""
-    return rec.span(name, lane, **args) if rec is not None else nullcontext()
 
 
 @dataclasses.dataclass
@@ -389,8 +382,9 @@ class SlotServer:
         ``_tap_sink``)."""
         sink = self._tap_sink
         if sink is not None:
-            sink(int(idx), np.asarray(toks), np.asarray(active),
-                 np.asarray(quarantined))
+            with span(self.recorder, "tap", "server"):
+                sink(int(idx), np.asarray(toks), np.asarray(active),
+                     np.asarray(quarantined))
 
     # ---- compiled programs -------------------------------------------------
     def chunk_fn(self):
@@ -750,155 +744,155 @@ class SlotServer:
                         f"slot loop passed its horizon ({horizon} steps) "
                         f"with {n_req - L.done} requests unfinished — "
                         "admission bookkeeping is stuck")
-                sweep0 = rec.now_ns() if rec is not None else 0
-                drain_events()
-                # -- scheduled driver preemption ---------------------------
-                if preempts:
-                    due_p = next(
-                        (p for p in preempts if start_t0 < p <= t), None)
-                    if due_p is not None:
-                        if snapshot is not None:
-                            if last_offered != t:
-                                snapshot.offer(t, state, meta=ledger_meta())
-                            snapshot.drain()
-                        raise ServePreempted(t, due_p)
-                # -- completions (deterministic, no readback) --------------
-                freed = sorted(
-                    (s for s in range(S)
-                     if L.slot_rid[s] >= 0 and L.fin[L.slot_rid[s]] <= t),
-                    key=lambda s: (L.fin[L.slot_rid[s]], s))
-                for s in freed:
-                    rid, L.slot_rid[s] = L.slot_rid[s], -1
-                    L.state_of[rid] = "done"
-                    trace.completed(rid, s, L.fin[rid], L.in_flight + 1)
-                    policy.notify_completion(rid)
-                    if rec is not None and rid in req_ns:
-                        # per-request lifetime on the slot's own lane
-                        rec.span_at("request", f"slot{s}", req_ns.pop(rid),
-                                    rec.now_ns(), rid=rid,
-                                    steps=L.fin[rid] - L.admit_t[rid] + 1)
-                        rec.count("completions")
-                # -- graceful drain (stop admitting, finish in-flight) -----
-                if (drain_after is not None and t >= drain_after
-                        and L.drain_t is None):
-                    L.drain_t = t
-                    drain_ns = rec.now_ns() if rec is not None else None
-                    for r in sorted(L.state_of):
-                        if L.state_of[r] == "queued":
-                            L.state_of[r] = "done"
-                            L.drained[r] = t
-                            trace.drained(r, t)
-                            policy.cancel(r)
-                    if rec is not None:
-                        rec.instant("drain_start", lane="server", step=t,
-                                    cancelled=len(L.drained),
-                                    in_flight=L.in_flight)
-                        rec.count("drained", len(L.drained))
-                # -- deadline timeouts (queue-wait budget) -----------------
-                if deadline is not None:
-                    for r in range(n_req):
-                        if L.state_of[r] != "queued":
-                            continue
-                        el = L.eligible[r]
-                        if el <= t and t - el > deadline:
-                            if retry is not None:
-                                tries = L.tries[r] = L.tries.get(r, 0) + 1
-                                trace.retried(r, tries)
-                                if tries < retry.max_attempts:
-                                    L.eligible[r] = (
-                                        t + retry.backoff_steps(tries))
-                                    if rec is not None:
-                                        rec.instant("retry", lane="server",
-                                                    rid=r, step=t,
-                                                    attempt=tries)
-                                        rec.count("retries")
-                                    continue
-                            L.timeouts[r] = t
-                            L.state_of[r] = "done"
-                            policy.cancel(r)
-                            trace.timed_out(r, t)
-                            if rec is not None:
-                                rec.instant("timeout", lane="server", rid=r,
-                                            step=t, wait=t - int(el))
-                                rec.count("timeouts")
-                # -- admissions into free slots ----------------------------
-                arrived = {r for r, st_r in L.state_of.items()
-                           if st_r == "queued" and L.eligible[r] <= t}
-                free = [s for s in range(S) if L.slot_rid[s] < 0]
-                while free:
-                    rid = policy.pick(arrived, L.in_flight)
-                    if rid is None:
-                        break
-                    s = free[0]
-                    tries = L.tries.get(rid, 0)
-                    pre = L.emitted.get(rid, [])
-                    e = len(pre)
-                    if e:
-                        # replay the recovered prefix: re-prefill
-                        # prompt + tokens-emitted-so-far
-                        pf_e = self.prefill_fn(plen + e)
-                        ptoks = jnp.asarray(
-                            np.concatenate(
-                                [prompts[rid],
-                                 np.asarray(pre, np.int64)])[None],
-                            jnp.int32)
-                    else:
-                        pf_e, ptoks = pf, prompts_dev[rid:rid + 1]
-                    key = jax.random.fold_in(base_key, rid)
-                    if tries:
-                        key = jax.random.fold_in(key, tries)
-                    rem0 = max_new - 1 - e
-                    with _span(rec, "prefill", "server", rid=rid,
-                               plen=plen + e):
-                        tok0, pcache = pf_e(params, ptoks)
-                    with _span(rec, "admit", "server", rid=rid, slot=s):
-                        state = admit(state, pcache, s, tok0[0],
-                                      jnp.int32(plen + e),
-                                      jnp.int32(rem0), key)
-                    L.outputs[rid] = [tok0]
-                    L.admit_t.setdefault(rid, t)
-                    L.fin[rid] = t + rem0
-                    trace.admitted(rid, t)
-                    arrived.discard(rid)
-                    if rec is not None:
-                        rec.hist("ttft_steps", t - int(arr[rid]))
-                        req_ns[rid] = rec.now_ns()
-                    if rem0 == 0:     # budget already emitted: completes
-                        L.state_of[rid] = "done"   # at admission
-                        trace.completed(rid, s, t, L.in_flight + 1)
+                with span(rec, "admission_sweep", "server", t=t):
+                    drain_events()
+                    # -- scheduled preemption of this process ---------------
+                    if preempts:
+                        due_p = next(
+                            (p for p in preempts if start_t0 < p <= t), None)
+                        if due_p is not None:
+                            if snapshot is not None:
+                                if last_offered != t:
+                                    snapshot.offer(t, state,
+                                                   meta=ledger_meta())
+                                snapshot.drain()
+                            raise ServePreempted(t, due_p)
+                    # -- completions (deterministic, no readback) -------------
+                    freed = sorted(
+                        (s for s in range(S)
+                         if L.slot_rid[s] >= 0 and L.fin[L.slot_rid[s]] <= t),
+                        key=lambda s: (L.fin[L.slot_rid[s]], s))
+                    for s in freed:
+                        rid, L.slot_rid[s] = L.slot_rid[s], -1
+                        L.state_of[rid] = "done"
+                        trace.completed(rid, s, L.fin[rid], L.in_flight + 1)
                         policy.notify_completion(rid)
                         if rec is not None and rid in req_ns:
-                            rec.span_at("request", f"slot{s}",
-                                        req_ns.pop(rid), rec.now_ns(),
-                                        rid=rid, steps=1)
+                            # per-request lifetime on the slot's own lane
+                            rec.span_at("request", f"slot{s}", req_ns.pop(rid),
+                                        rec.now_ns(), rid=rid,
+                                        steps=L.fin[rid] - L.admit_t[rid] + 1)
                             rec.count("completions")
-                    else:
-                        L.slot_rid[s] = rid
-                        L.state_of[rid] = "inflight"
-                        free.pop(0)
-                # -- overload shedding (bounded admission queue) -----------
-                if overload is not None:
-                    waiting = sorted(
-                        (r for r, st_r in L.state_of.items()
-                         if st_r == "queued" and L.eligible[r] <= t),
-                        key=lambda r: (L.eligible[r], r))
-                    excess = len(waiting) - overload.queue_cap
-                    if excess > 0:
-                        victims = (waiting[-excess:]
-                                   if overload.shed == "reject-new"
-                                   else waiting[:excess])
-                        for r in victims:
-                            L.state_of[r] = "done"
-                            L.shed[r] = t
-                            trace.shed(r, t)
-                            policy.cancel(r)
-                            if rec is not None:
-                                rec.instant("shed", lane="server", rid=r,
-                                            step=t, policy=overload.shed)
-                                rec.count("shed")
+                    # -- graceful drain (stop admitting, finish in-flight) ----
+                    if (drain_after is not None and t >= drain_after
+                            and L.drain_t is None):
+                        L.drain_t = t
+                        drain_ns = rec.now_ns() if rec is not None else None
+                        for r in sorted(L.state_of):
+                            if L.state_of[r] == "queued":
+                                L.state_of[r] = "done"
+                                L.drained[r] = t
+                                trace.drained(r, t)
+                                policy.cancel(r)
+                        if rec is not None:
+                            rec.instant("drain_start", lane="server", step=t,
+                                        cancelled=len(L.drained),
+                                        in_flight=L.in_flight)
+                            rec.count("drained", len(L.drained))
+                    # -- deadline timeouts (queue-wait budget) ----------------
+                    if deadline is not None:
+                        for r in range(n_req):
+                            if L.state_of[r] != "queued":
+                                continue
+                            el = L.eligible[r]
+                            if el <= t and t - el > deadline:
+                                if retry is not None:
+                                    tries = L.tries[r] = L.tries.get(r, 0) + 1
+                                    trace.retried(r, tries)
+                                    if tries < retry.max_attempts:
+                                        L.eligible[r] = (
+                                            t + retry.backoff_steps(tries))
+                                        if rec is not None:
+                                            rec.instant("retry", lane="server",
+                                                        rid=r, step=t,
+                                                        attempt=tries)
+                                            rec.count("retries")
+                                        continue
+                                L.timeouts[r] = t
+                                L.state_of[r] = "done"
+                                policy.cancel(r)
+                                trace.timed_out(r, t)
+                                if rec is not None:
+                                    rec.instant("timeout", lane="server",
+                                                rid=r, step=t,
+                                                wait=t - int(el))
+                                    rec.count("timeouts")
+                    # -- admissions into free slots ---------------------------
+                    arrived = {r for r, st_r in L.state_of.items()
+                               if st_r == "queued" and L.eligible[r] <= t}
+                    free = [s for s in range(S) if L.slot_rid[s] < 0]
+                    while free:
+                        rid = policy.pick(arrived, L.in_flight)
+                        if rid is None:
+                            break
+                        s = free[0]
+                        tries = L.tries.get(rid, 0)
+                        pre = L.emitted.get(rid, [])
+                        e = len(pre)
+                        if e:
+                            # replay the recovered prefix: re-prefill
+                            # prompt + tokens-emitted-so-far
+                            pf_e = self.prefill_fn(plen + e)
+                            ptoks = jnp.asarray(
+                                np.concatenate(
+                                    [prompts[rid],
+                                     np.asarray(pre, np.int64)])[None],
+                                jnp.int32)
+                        else:
+                            pf_e, ptoks = pf, prompts_dev[rid:rid + 1]
+                        key = jax.random.fold_in(base_key, rid)
+                        if tries:
+                            key = jax.random.fold_in(key, tries)
+                        rem0 = max_new - 1 - e
+                        with span(rec, "prefill", "server", rid=rid,
+                                  plen=plen + e):
+                            tok0, pcache = pf_e(params, ptoks)
+                        with span(rec, "admit", "server", rid=rid, slot=s):
+                            state = admit(state, pcache, s, tok0[0],
+                                          jnp.int32(plen + e),
+                                          jnp.int32(rem0), key)
+                        L.outputs[rid] = [tok0]
+                        L.admit_t.setdefault(rid, t)
+                        L.fin[rid] = t + rem0
+                        trace.admitted(rid, t)
+                        arrived.discard(rid)
+                        if rec is not None:
+                            rec.hist("ttft_steps", t - int(arr[rid]))
+                            req_ns[rid] = rec.now_ns()
+                        if rem0 == 0:     # budget already emitted: completes
+                            L.state_of[rid] = "done"   # at admission
+                            trace.completed(rid, s, t, L.in_flight + 1)
+                            policy.notify_completion(rid)
+                            if rec is not None and rid in req_ns:
+                                rec.span_at("request", f"slot{s}",
+                                            req_ns.pop(rid), rec.now_ns(),
+                                            rid=rid, steps=1)
+                                rec.count("completions")
+                        else:
+                            L.slot_rid[s] = rid
+                            L.state_of[rid] = "inflight"
+                            free.pop(0)
+                    # -- overload shedding (bounded admission queue) ----------
+                    if overload is not None:
+                        waiting = sorted(
+                            (r for r, st_r in L.state_of.items()
+                             if st_r == "queued" and L.eligible[r] <= t),
+                            key=lambda r: (L.eligible[r], r))
+                        excess = len(waiting) - overload.queue_cap
+                        if excess > 0:
+                            victims = (waiting[-excess:]
+                                       if overload.shed == "reject-new"
+                                       else waiting[:excess])
+                            for r in victims:
+                                L.state_of[r] = "done"
+                                L.shed[r] = t
+                                trace.shed(r, t)
+                                policy.cancel(r)
+                                if rec is not None:
+                                    rec.instant("shed", lane="server", rid=r,
+                                                step=t, policy=overload.shed)
+                                    rec.count("shed")
                 if rec is not None:
-                    rec.span_at("admission_sweep", "server", sweep0,
-                                rec.now_ns(), t=t)
                     rec.gauge("in_flight", L.in_flight, lane="server")
                     rec.gauge("occupancy", L.in_flight / S, lane="server")
                 if L.done >= n_req:
@@ -933,21 +927,21 @@ class SlotServer:
                                 hit = True
                     if hit:
                         pz = mask
-                with _span(rec, "launch", "server", t=t,
-                           in_flight=L.in_flight):
+                with span(rec, "launch", "server", t=t,
+                          in_flight=L.in_flight):
                     state = chunk(params, state, jnp.int32(t), pz)
                 chunks_run += 1
                 L.chunks += 1
                 t += K
                 L.t = t
                 if sync:
-                    with _span(rec, "chunk_barrier", "server", t=t):
+                    with span(rec, "chunk_barrier", "server", t=t):
                         jax.effects_barrier()
                 if snapshot is not None and snapshot.due(t, 1 << 62):
                     drain_events()   # ledger must reflect delivered taps
                     snapshot.offer(t, state, meta=ledger_meta())
                     last_offered = t
-            with _span(rec, "barrier", "server"):
+            with span(rec, "barrier", "server"):
                 state = jax.block_until_ready(state)
                 jax.effects_barrier()
             drain_events()

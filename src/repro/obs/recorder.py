@@ -8,14 +8,42 @@ end-of-run :meth:`summary` dict that rides ``RunResult.extra["obs"]``
 through serialization (plain scalars only — it must survive
 ``RunResult.to_json`` round-trips).
 
-Every instrumented call site guards with ``if recorder is not None`` —
-an un-observed run pays literally zero (no null-object dispatch on the
-tap hot path).
+Instrumented call sites open their spans through :func:`span`, which
+always writes a ``jax.profiler.TraceAnnotation`` (so the span lands in
+any profiler trace, on the device's clock) and, with a recorder
+attached, records the same span into its :class:`Tracer`.  Instants,
+counters and gauges still guard with ``if recorder is not None`` — an
+un-observed run formats nothing for them.
 """
 from __future__ import annotations
 
+from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
+
 from .schema import METRICS_SCHEMA_VERSION
 from .tracer import Tracer
+
+
+def span(rec, name: str, lane: str, **args):
+    """Context manager for one span of the program.
+
+    Always opens ``TraceAnnotation(f"{lane}.{name}")``: with the profiler
+    off that costs well under a microsecond, and under ``jax.profiler``
+    the span sits in the host plane next to the device's ops.  With a
+    :class:`Recorder` the span is also recorded in its tracer as
+    ``name`` on ``lane`` with ``args`` (which then ride the annotation as
+    its stats too); without one ``args`` are never formatted."""
+    if rec is None:
+        return TraceAnnotation(f"{lane}.{name}")
+    return _recorded(rec, name, lane, args)
+
+
+@contextmanager
+def _recorded(rec, name, lane, args):
+    with TraceAnnotation(f"{lane}.{name}", **args), \
+            rec.span(name, lane, **args):
+        yield
 
 
 class Recorder:

@@ -43,12 +43,11 @@ device-synthesised batches, same plan slices; only the dispatch differs).
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
 from typing import Callable, Optional
 
 import numpy as np
 
-from ..obs import CompileWatch
+from ..obs import CompileWatch, span
 from .plan import RunPlan
 
 #: fixed metric order of the on-device accumulator row; mirrors the dict
@@ -60,12 +59,6 @@ METRICS = ("loss", "ce", "aux", "grad_norm", "participation",
 _LOSS_IDX = METRICS.index("loss")
 _SKIP_IDX = METRICS.index("skipped")
 _GSCALE_IDX = METRICS.index("gscale")
-
-
-def _span(rec, name, lane, **args):
-    """Optional-recorder span: a real span when observing, else a no-op
-    (un-observed runs must pay nothing on the dispatch path)."""
-    return rec.span(name, lane, **args) if rec is not None else nullcontext()
 
 #: metric transport modes of the scan executor
 METRIC_MODES = ("chunk", "tap", "none")
@@ -293,7 +286,8 @@ class PlanExecutor:
         ``_tap_sink``)."""
         sink = self._tap_sink
         if sink is not None:
-            sink(int(idx), np.asarray(row))
+            with span(self.recorder, "sink", "tap"):
+                sink(int(idx), np.asarray(row))
 
     def _chunk_jit(self, mode: str):
         """Jitted ``chunk(state, xs)`` for one metric mode, where ``xs``
@@ -387,8 +381,8 @@ class PlanExecutor:
         chunk is already free to launch), which is the barrier-free
         durability contract."""
         if snapshot is not None and snapshot.due(hi, self.plan.rounds):
-            with _span(self.recorder, "snapshot_offer", "snapshot",
-                       round=hi):
+            with span(self.recorder, "snapshot_offer", "snapshot",
+                      round=hi):
                 snapshot.offer(hi, state)
             stats.snapshots += 1
 
@@ -517,7 +511,7 @@ class PlanExecutor:
                 for lo, hi in bounds:
                     if breaker is not None and breaker.tripped:
                         break               # stop launching; queue drains
-                    with _span(rec, "launch", "executor", lo=lo, hi=hi):
+                    with span(rec, "launch", "executor", lo=lo, hi=hi):
                         state = fn(state, self._slices(lo, hi))
                     stats.launches += 1
                     launched_hi = hi
@@ -526,7 +520,7 @@ class PlanExecutor:
                 # enqueued chunks, then drains the callback queue — array
                 # readiness alone does NOT guarantee pending io_callbacks
                 # have run on every backend
-                with _span(rec, "barrier", "executor"):
+                with span(rec, "barrier", "executor"):
                     state = jax.block_until_ready(state)
                     jax.effects_barrier()
             finally:
@@ -553,11 +547,11 @@ class PlanExecutor:
 
         if metrics == "none":
             for lo, hi in bounds:
-                with _span(rec, "launch", "executor", lo=lo, hi=hi):
+                with span(rec, "launch", "executor", lo=lo, hi=hi):
                     state = fn(state, self._slices(lo, hi))
                 stats.launches += 1
                 self._maybe_snapshot(snapshot, hi, state, stats)
-            with _span(rec, "barrier", "executor"):
+            with span(rec, "barrier", "executor"):
                 state = jax.block_until_ready(state)
             if snapshot is not None:
                 snapshot.drain()
@@ -568,12 +562,12 @@ class PlanExecutor:
         # metrics == "chunk"
         rows = []
         for lo, hi in bounds:
-            with _span(rec, "launch", "executor", lo=lo, hi=hi):
+            with span(rec, "launch", "executor", lo=lo, hi=hi):
                 state, ms = fn(state, self._slices(lo, hi))
             stats.launches += 1
             self._maybe_snapshot(snapshot, hi, state, stats)
             if on_step is not None:
-                with _span(rec, "host_sync", "executor", lo=lo, hi=hi):
+                with span(rec, "host_sync", "executor", lo=lo, hi=hi):
                     ms = np.asarray(ms)      # blocking readback per chunk
                 stats.host_syncs += 1
                 for i in range(lo, hi):
@@ -582,10 +576,10 @@ class PlanExecutor:
         if on_step is None and rows:
             # overlapped path: every chunk is already enqueued; block once
             # and read all metric buffers back in one sync point
-            with _span(rec, "host_sync", "executor", deferred=True):
+            with span(rec, "host_sync", "executor", deferred=True):
                 rows = [np.asarray(r) for r in jax.block_until_ready(rows)]
             stats.host_syncs = 1
-        with _span(rec, "barrier", "executor"):
+        with span(rec, "barrier", "executor"):
             state = jax.block_until_ready(state)
         if snapshot is not None:
             snapshot.drain()
@@ -673,7 +667,7 @@ class PlanExecutor:
             shared = self._slices(lo, hi)
             del shared["scale"]          # per-γ rows replace the base scale
             scales = plan.grid_slice(lo, hi)
-            with _span(rec, "launch", "executor", lo=lo, hi=hi, grid=g):
+            with span(rec, "launch", "executor", lo=lo, hi=hi, grid=g):
                 out = fn(states, shared, scales)
             states, ms = out if metrics == "chunk" else (out, None)
             stats.launches += 1
@@ -682,10 +676,10 @@ class PlanExecutor:
             if ms is not None:
                 rows.append(ms)
         if rows:
-            with _span(rec, "host_sync", "executor", deferred=True):
+            with span(rec, "host_sync", "executor", deferred=True):
                 rows = [np.asarray(r) for r in jax.block_until_ready(rows)]
             stats.host_syncs = 1
-        with _span(rec, "barrier", "executor"):
+        with span(rec, "barrier", "executor"):
             states = jax.block_until_ready(states)
         if snapshot is not None:
             snapshot.drain()
@@ -736,10 +730,10 @@ class PlanExecutor:
                 args += (jnp.float32(plan.grad_density[i]),)
             if with_gain:
                 args += (jnp.asarray(plan.fault_gain[i]),)
-            with _span(rec, "launch", "executor", lo=i, hi=i + 1):
+            with span(rec, "launch", "executor", lo=i, hi=i + 1):
                 state, m = step(*args)
             stats.launches += 1
-            with _span(rec, "host_sync", "executor", lo=i, hi=i + 1):
+            with span(rec, "host_sync", "executor", lo=i, hi=i + 1):
                 row = {k: float(m[k]) for k in METRICS}  # host sync / round
             stats.host_syncs += 1
             rows.append([row[k] for k in METRICS])

@@ -26,8 +26,9 @@ import pytest
 
 from repro.api import ExperimentSpec, RunResult, TrainJob, TrainerBackend
 from repro.obs import (CompileWatch, METRICS_SCHEMA_VERSION, Recorder,
-                       RetraceError, SchemaError, Tracer, render_summary,
-                       validate_line, validate_lines, validate_metrics_log)
+                       RetraceError, SchemaError, Tracer, compile_log,
+                       render_summary, span, validate_line, validate_lines,
+                       validate_metrics_log)
 from repro.obs import schema as obs_schema
 from repro.runtime import PlanExecutor, compile_plan
 
@@ -258,6 +259,106 @@ def test_compile_watch_unsizeable_fn_degrades():
     watch.register("plain", lambda x: x)             # no _cache_size
     assert watch.counts() == {"plain": -1}
     watch.observe()                                  # must not raise
+    # wrapped, its compile is still credited, once: the later calls only
+    # look their jaxpr up again (an ordered io_callback keeps the jit off
+    # JAX's C++ dispatch path), and a lookup alone is no compile
+    tapped = _tapped_jit(2.0)
+    fn = watch.wrap("unsized", lambda x: tapped(x))
+    x = jax.numpy.ones(5)
+    n0 = len(compile_log())
+    fn(x)
+    mine = compile_log()[n0:]
+    assert {e.program for e in mine} == {"unsized"}
+    assert "backend" in {e.phase for e in mine}
+    n1 = len(compile_log())
+    for _ in range(3):
+        fn(x)
+    jax.effects_barrier()
+    assert len(compile_log()) == n1
+
+
+def _tapped_jit(c):
+    from jax.experimental import io_callback
+    return jax.jit(lambda x: io_callback(lambda v: None, None, x,
+                                         ordered=True) or x + c)
+
+
+def test_compile_log_credits_the_watched_program():
+    rec = Recorder()
+    watch = CompileWatch(rec)
+    fn = watch.wrap("probe", jax.jit(lambda x: x * 3.0 + 1.0))
+    x = jax.numpy.ones(7)
+    n0 = len(compile_log())
+    fn(x)
+    mine = compile_log()[n0:]
+    assert mine and {e.program for e in mine} == {"probe"}
+    assert {"trace", "lower", "backend"} <= {e.phase for e in mine}
+    assert sum(e.end_s - e.start_s for e in mine
+               if e.phase == "backend") > 0
+    assert all(e.end_s >= e.start_s for e in mine)
+    n1 = len(compile_log())
+    fn(x)                                          # warm: nothing compiles
+    assert len(compile_log()) == n1
+    # with a recorder: one compile span per entry, and the seconds
+    assert rec.tracer.phase_table()["compile"]["count"] == len(mine)
+    assert 0 < rec.tracer.counters()["compile_s"] \
+        <= max(e.end_s for e in mine) - min(e.start_s for e in mine)
+
+    # an ordered io_callback keeps a jit off JAX's C++ dispatch path, so
+    # every call looks its jaxpr up again; a call that compiles nothing
+    # credits nothing
+    tapped = watch.wrap("tapped", _tapped_jit(1.0))
+    tapped(x)
+    n2 = len(compile_log())
+    tapped(x)
+    jax.effects_barrier()
+    assert len(compile_log()) == n2
+    n1 = n2
+
+    jax.jit(lambda x: x - 7.0)(x)                  # no watch sees this one
+    unwatched = compile_log()[n1:]
+    assert unwatched and {e.program for e in unwatched} == {None}
+    assert "backend" in {e.phase for e in unwatched}
+
+
+def test_compile_log_keeps_unwatched_compiles_not_lookups():
+    """An unwatched jit off the C++ dispatch path logs its one compile
+    (trace with it) under ``None``, and nothing for later calls."""
+    tapped = _tapped_jit(3.0)
+    x = jax.numpy.ones(6)
+    n0 = len(compile_log())
+    tapped(x)
+    mine = compile_log()[n0:]
+    assert {e.program for e in mine} == {None}
+    assert {"trace", "lower", "backend"} <= {e.phase for e in mine}
+    n1 = len(compile_log())
+    for _ in range(3):
+        tapped(x)
+    jax.effects_barrier()
+    assert len(compile_log()) == n1
+
+
+def test_span_is_a_profiler_annotation(tmp_path):
+    """Without a recorder the span still lands in the profiler's host
+    plane as ``lane.name``; with one, the Tracer records it unchanged."""
+    from jax.profiler import ProfileData
+
+    rec = Recorder()
+    with jax.profiler.trace(str(tmp_path)):
+        with span(None, "probe", "lane"):
+            jax.numpy.ones(3).block_until_ready()
+        with span(rec, "probe_rec", "lane", rid=3):
+            pass
+    (path,) = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"lane.probe", "lane.probe_rec"} <= names
+    assert rec.tracer.phase_table()["probe_rec"]["count"] == 1
+    (x,) = [e for e in rec.tracer.chrome_trace()["traceEvents"]
+            if e["ph"] == "X"]
+    assert (x["name"], x["cat"], x["args"]) == ("probe_rec", "lane",
+                                                {"rid": 3})
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +507,17 @@ def test_slot_server_trace_tells_admission_story(tmp_path):
     assert phases["admit"]["count"] == 5
     assert phases["prefill"]["count"] == 5
     assert phases["request"]["count"] == 5           # one span per rid
+    assert phases["tap"]["count"] == res.tap_rows    # one per decode step
     trace = json.load(open(rec.export_chrome(str(tmp_path / "s.json"))))
+    # the sweep is a context span: it holds the prefills and admits it
+    # dispatches (1e-3 µs of slack for the ns -> µs division)
+    sweeps = [(e["ts"], e["ts"] + e["dur"]) for e in trace["traceEvents"]
+              if e.get("name") == "admission_sweep"]
+    inner = [e for e in trace["traceEvents"]
+             if e.get("name") in ("prefill", "admit")]
+    assert len(inner) == 10
+    assert all(any(a - 1e-3 <= e["ts"] and e["ts"] + e["dur"] <= b + 1e-3
+                   for a, b in sweeps) for e in inner)
     lanes = {e["args"]["name"] for e in trace["traceEvents"]
              if e["ph"] == "M" and e["name"] == "thread_name"}
     assert {"server", "slot0", "slot1"} <= lanes
