@@ -118,19 +118,31 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
     return out
 
 
-def decode_attention(q, k_cache, v_cache, cache_positions, pos,
-                     window: Optional[int] = None):
-    """One-token attention vs a ring-buffer cache.
+def decode_attention(q, k_cache, v_cache, cache_positions, pos, k_new, v_new,
+                     slot, window: Optional[int] = None):
+    """One-token attention vs a ring-buffer cache plus the token's own k/v.
 
-    q: (B,1,H,D); caches: (B,W,KV,D); cache_positions: (W,) int32 holding the
-    absolute position stored in each slot (−1 = empty); pos: scalar int32 of
-    the current token.  The current token's own k/v must already be written.
+    q: (B,1,H,D); caches: (B,W,KV·D), the heads folded into the minor dim;
+    cache_positions: (W,) int32 holding the absolute position stored in
+    each slot (−1 = empty); pos: scalar int32 of the current token;
+    k_new/v_new: (B,1,KV,D), the current token's own k/v, which is not in
+    the cache: its score joins the ring's in one softmax (a max/sum merge).
+    ``slot`` (= pos mod W) is the ring row the step overwrites afterwards;
+    it holds position pos − W or nothing and is masked, so attention covers
+    exactly the last W positions.
 
-    Ragged (slot-server) variant: ``pos`` is (B,) and ``cache_positions`` is
-    (B, W) — each batch row decodes at its own absolute position, so the
-    validity mask is per-row.  The scalar path's op sequence is unchanged
-    (the bias broadcasts identically), keeping lock-step decoding
-    bit-for-bit what it was.
+    Ragged (slot-server) variant: ``pos`` and ``slot`` are (B,) and
+    ``cache_positions`` is (B, W) — each batch row decodes at its own
+    absolute position, so the validity mask is per-row.  Both variants run
+    the same op sequence (the scalar bias broadcasts), so lock-step and
+    slot decoding agree bit for bit.
+
+    Each query head sits in its KV head's D lanes of the folded dim, zeros
+    elsewhere, so one contraction over KV·D scores every head and reads the
+    cache in the layout it is stored in (splitting the lanes into (KV, D)
+    makes the compiler copy and relayout each layer's ring); the value
+    product likewise yields every KV head's mix, of which each query head
+    keeps its own.  The zeros add exactly nothing.
 
     The score tensor is constrained to keep the cache's ctx sharding so
     GSPMD computes a *distributed* softmax (partial max/sum + small
@@ -138,34 +150,45 @@ def decode_attention(q, k_cache, v_cache, cache_positions, pos,
     """
     from ..distributed.sharding import shard_activation
 
+    W = k_cache.shape[1]
     if jnp.ndim(pos) == 1:                            # ragged: per-row pos
         valid = (cache_positions >= 0) & (cache_positions <= pos[:, None])
+        valid &= jnp.arange(W)[None, :] != slot[:, None]
         if window is not None:
             valid &= cache_positions > (pos[:, None] - window)
         bias = jnp.where(valid, 0.0, NEG_INF).astype(F32)[:, None]  # (B,1=Sq,W)
     else:
         valid = (cache_positions >= 0) & (cache_positions <= pos)
+        valid &= jnp.arange(W) != slot
         if window is not None:
             valid &= cache_positions > pos - window
         bias = jnp.where(valid, 0.0, NEG_INF).astype(F32)[None, None]  # (1,1=Sq,W)
 
     B, Sq, H, D = q.shape
-    KV = k_cache.shape[2]
+    KV = k_new.shape[2]
     G = H // KV
     qr = q.reshape(B, Sq, KV, G, D)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qr, k_cache,
+    eye = jnp.eye(KV, dtype=q.dtype)[:, None, :, None]    # (KV,1,KV,1)
+    qx = (qr[..., None, :] * eye).reshape(B, Sq, KV, G, KV * D)
+    scores = jnp.einsum("bqkgc,bsc->bkgqs", qx, k_cache,
                         preferred_element_type=F32) / np.sqrt(D)
     scores = scores + bias[:, None, None]             # (B|1,1,1,Sq,W)
-    scores = shard_activation(
-        scores, ("batch", "kv_heads", None, None, "ctx"))
-    m = jnp.max(scores, axis=-1, keepdims=True)
+    scores = shard_activation(scores, ("batch", None, None, None, "ctx"))
+    own = jnp.einsum("bqkgd,bqkd->bkgq", qr, k_new,
+                     preferred_element_type=F32)[..., None] / np.sqrt(D)
+    m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), own)
     p = jnp.exp(scores - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
+    p_own = jnp.exp(own - m)
+    l = jnp.sum(p, axis=-1, keepdims=True) + p_own
     probs = (p / l).astype(v_cache.dtype)
-    probs = shard_activation(
-        probs, ("batch", "kv_heads", None, None, "ctx"))
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v_cache,
+    probs = shard_activation(probs, ("batch", None, None, None, "ctx"))
+    out = jnp.einsum("bkgqs,bsc->bkgqc", probs, v_cache,
                      preferred_element_type=F32)
+    out = jnp.einsum("bkgqjd,kj->bqkgd", out.reshape(B, KV, G, Sq, KV, D),
+                     jnp.eye(KV, dtype=F32))
+    out = out + jnp.einsum("bkgq,bqkd->bqkgd",
+                           (p_own / l)[..., 0].astype(v_new.dtype), v_new,
+                           preferred_element_type=F32)
     return out.reshape(B, Sq, H, D).astype(v_cache.dtype)
 
 

@@ -422,8 +422,8 @@ def loss_fn(cfg: ArchConfig, params, batch, example_weights=None,
 # ============================================================================
 
 def _ring_from_seq(k_seq, v_seq, W: int):
-    """(L,B,S,KV,D) stacked per-layer k/v → ring cache of the last W tokens,
-    placed at slot = pos mod W, plus the positions buffer.
+    """(L,B,S,KV,D) stacked per-layer k/v → ring cache (L,B,W,KV·D) of the
+    last W tokens, placed at slot = pos mod W, plus the positions buffer.
 
     The slots are static, so the ring is a pad (S < W) or a rotation
     (S ≥ W) of the last W tokens: no scatter.  Two sibling scatters into
@@ -437,9 +437,11 @@ def _ring_from_seq(k_seq, v_seq, W: int):
     def ring(x):
         x = x[:, :, S - take:]
         if take < W:                      # slots 0..S-1, the rest empty
-            return jnp.pad(x, ((0, 0), (0, 0), (0, W - take))
-                           + ((0, 0),) * (x.ndim - 3))
-        return jnp.roll(x, S % W, axis=2)
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, W - take))
+                        + ((0, 0),) * (x.ndim - 3))
+        else:
+            x = jnp.roll(x, S % W, axis=2)
+        return x.reshape(x.shape[:3] + (-1,))
 
     return ring(k_seq), ring(v_seq), jnp.asarray(positions)
 
@@ -553,6 +555,14 @@ def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int, *,
     grows a per-row batch axis ((batch, W) instead of the shared (W,)) so
     each slot tracks its own absolute position.  Every other leaf already
     carries a batch axis and is unchanged.
+
+    A K/V ring is (layers, batch, W, KV·Dh): the heads fold into one minor
+    dim, which the chip tiles with W without padding (a (KV, Dh) = (2, 64)
+    pair would not be), so a decode step's row writes and its reads agree
+    on one layout and the ring is never relayouted.  The model axis shards
+    a ring's positions (ctx), never the folded dim: every head's score
+    contracts over all of it (``layers.decode_attention``), so splitting it
+    would all-reduce the scores.
     """
     W = min(cfg.sliding_window or ctx_len, ctx_len)
     KV, Dh, nl = cfg.n_kv_heads, cfg.d_head, cfg.n_layers
@@ -562,10 +572,10 @@ def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int, *,
 
     def ring(lyrs):
         return {
-            "k": Spec((lyrs, batch, W, KV, Dh),
-                      ("layers", "batch", "ctx", "kv_heads", "head"), "zeros", dt),
-            "v": Spec((lyrs, batch, W, KV, Dh),
-                      ("layers", "batch", "ctx", "kv_heads", "head"), "zeros", dt),
+            "k": Spec((lyrs, batch, W, KV * Dh),
+                      ("layers", "batch", "ctx", None), "zeros", dt),
+            "v": Spec((lyrs, batch, W, KV * Dh),
+                      ("layers", "batch", "ctx", None), "zeros", dt),
         }
 
     def ssm_states(lyrs):
@@ -614,12 +624,14 @@ def init_cache(cfg: ArchConfig, batch: int, ctx_len: int, *,
 
 
 def _decode_attn(cfg, p, h, kc, vc, cache_positions, pos, window, slot):
-    """One-token attention; returns (h', new_k_slice, new_v_slice).
+    """One-token attention over the ring as it stands plus the token's own
+    k/v; returns (h', k, v) with the token's (B, KV·Dh) rows, which
+    ``_write_rows`` puts at ``slot`` once every layer has attended.
 
     ``pos``/``slot`` scalar: lock-step decoding (all rows share one
     position).  ``pos``/``slot`` (B,): ragged decoding — each row carries
-    its own position, writes its own ring slot, and ``cache_positions`` is
-    the per-row (B, W) buffer.
+    its own position and ring slot, and ``cache_positions`` is the per-row
+    (B, W) buffer.
     """
     x = L.rms_norm(h, p["norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -634,15 +646,32 @@ def _decode_attn(cfg, p, h, kc, vc, cache_positions, pos, window, slot):
     posv = pos[:, None] if ragged else jnp.full((1,), pos)
     q = L.rope(q, posv, cfg.rope_theta)
     k = L.rope(k, posv, cfg.rope_theta)
-    if ragged:
-        rows = jnp.arange(kc.shape[0])
-        kc = kc.at[rows, slot].set(k[:, 0])
-        vc = vc.at[rows, slot].set(v[:, 0])
-    else:
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k, slot, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v, slot, axis=1)
-    o = L.decode_attention(q, kc, vc, cache_positions, pos, window=window)
-    return h + jnp.einsum("bshk,hkd->bsd", o, p["wo"]), kc, vc
+    o = L.decode_attention(q, kc, vc, cache_positions, pos, k, v, slot,
+                           window=window)
+    B = k.shape[0]
+    return (h + jnp.einsum("bshk,hkd->bsd", o, p["wo"]), k.reshape(B, -1),
+            v.reshape(B, -1))
+
+
+def _write_rows(ring, k, v, slot):
+    """Write one step's rows into a ring {"k", "v"} of (layers, B, W, KV·Dh):
+    ``k``/``v`` (layers, B, KV·Dh) land at ring slot ``slot`` (scalar, or
+    (B,) per row).  One update per ring for all layers, in place in a
+    donated or scan-carried ring.  The ragged write scatters single
+    KV·Dh-wide rows, indexed by (layer, row, slot): a scatter whose window
+    spans the layers would ask for a layout with the layers minor, and the
+    ring would then be relayouted for the reads at every step."""
+    def put(c, new):
+        if jnp.ndim(slot) == 1:
+            nl, B = c.shape[:2]
+            li = jnp.broadcast_to(jnp.arange(nl)[:, None], (nl, B))
+            bi = jnp.broadcast_to(jnp.arange(B)[None, :], (nl, B))
+            si = jnp.broadcast_to(slot[None, :], (nl, B))
+            return c.at[li, bi, si].set(new)
+        return jax.lax.dynamic_update_slice_in_dim(c, new[:, :, None], slot,
+                                                   axis=2)
+
+    return {"k": put(ring["k"], k), "v": put(ring["v"], v)}
 
 
 def _decode_cross(cfg, p, h, ck, cv):
@@ -679,6 +708,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
     (slot-server) decoding against a cache built with
     ``cache_specs(..., ragged=True)`` — the positions buffer is then
     (B, W) and every row writes its own ring slot.
+    The layer loop only reads the K/V rings; it returns the step's new rows,
+    which one ``_write_rows`` per ring stores after the loop.
     Returns (logits (B, V), new_cache).
     """
     W = min(cfg.sliding_window or ctx_len, ctx_len)
@@ -703,17 +734,17 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
     if fam in ("dense", "vlm", "moe"):
         def f(x, inp):
             p, kc, vc = inp
-            x, kc, vc = _decode_attn(cfg, p["attn"], x, kc, vc, cpos, pos,
-                                     window, slot)
+            x, k, v = _decode_attn(cfg, p["attn"], x, kc, vc, cpos, pos,
+                                   window, slot)
             if fam == "moe":
                 x, _ = _apply_moe(cfg, p["moe"], x)
             else:
                 x = _apply_mlp(cfg, p["mlp"], x)
-            return x, (kc, vc)
+            return x, (k, v)
 
         h, (ks, vs) = jax.lax.scan(
             f, h, (params["blocks"], cache["self"]["k"], cache["self"]["v"]))
-        cache["self"] = {"k": ks, "v": vs}
+        cache["self"] = _write_rows(cache["self"], ks, vs, slot)
     elif fam == "ssm":
         def f(x, inp):
             p, cs, ss = inp
@@ -734,7 +765,7 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
 
         def fg(x, inp):
             pg, kc, vc, csg, ssg = inp
-            x, kc, vc = _decode_attn(cfg, sa, x, kc, vc, cpos, pos, window, slot)
+            x, k, v = _decode_attn(cfg, sa, x, kc, vc, cpos, pos, window, slot)
             x = _apply_mlp(cfg, sm, x)
 
             def fi(y, inner):
@@ -743,11 +774,11 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
                 return y, (cs, ss)
 
             x, (csg, ssg) = jax.lax.scan(fi, x, (pg, csg, ssg))
-            return x, (kc, vc, csg, ssg)
+            return x, (k, v, csg, ssg)
 
         h, (ks, vs, cs, ss) = jax.lax.scan(
             fg, h, (grouped, cache["attn"]["k"], cache["attn"]["v"], conv_g, ssd_g))
-        cache["attn"] = {"k": ks, "v": vs}
+        cache["attn"] = _write_rows(cache["attn"], ks, vs, slot)
         cache["ssm"] = {"conv": cs.reshape(cache["ssm"]["conv"].shape),
                         "ssd": ss.reshape(cache["ssm"]["ssd"].shape)}
         if "ssm_tail" in cache:
@@ -763,16 +794,16 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
     elif fam == "audio":
         def f(x, inp):
             p, kc, vc, ck, cv = inp
-            x, kc, vc = _decode_attn(cfg, p["attn"], x, kc, vc, cpos, pos,
-                                     window, slot)
+            x, k, v = _decode_attn(cfg, p["attn"], x, kc, vc, cpos, pos,
+                                   window, slot)
             x = _decode_cross(cfg, p["cross"], x, ck, cv)
             x = _apply_mlp(cfg, p["mlp"], x)
-            return x, (kc, vc)
+            return x, (k, v)
 
         h, (ks, vs) = jax.lax.scan(
             f, h, (params["blocks"], cache["self"]["k"], cache["self"]["v"],
                    cache["cross_k"], cache["cross_v"]))
-        cache["self"] = {"k": ks, "v": vs}
+        cache["self"] = _write_rows(cache["self"], ks, vs, slot)
     else:
         raise ValueError(fam)
 

@@ -12,6 +12,7 @@ from repro.models import (
     forward_logits, loss_fn, init_cache, decode_step, batch_specs,
     init_tree, abstract_tree,
 )
+from repro.models import layers as L, model as M
 from repro.models.specs import Spec
 
 ARCH_NAMES = sorted(ARCHS)
@@ -193,3 +194,152 @@ def test_prefill_then_decode_matches_full_forward(name):
         np.testing.assert_allclose(np.asarray(lg, np.float32),
                                    np.asarray(full[:, pos], np.float32),
                                    rtol=7e-2, atol=7e-2)
+
+
+# ---------------------------------------------------------------------------
+# decode step against the write-then-attend oracle
+# ---------------------------------------------------------------------------
+
+def _oracle_attn(cfg, p, h, kc, vc, cpos, pos, window, slot):
+    """The decode attention as the step used to run it: write the token's
+    k/v row into the layer's ring, then attend over the whole ring."""
+    x = L.rms_norm(h, p["norm"], cfg.norm_eps)
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+    if cfg.qkv_bias:
+        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if cfg.qk_norm:
+        q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
+    ragged = jnp.ndim(pos) == 1
+    posv = pos[:, None] if ragged else jnp.full((1,), pos)
+    q, k = L.rope(q, posv, cfg.rope_theta), L.rope(k, posv, cfg.rope_theta)
+    B, W = kc.shape[:2]
+    rows = jnp.arange(B) if ragged else slice(None)
+    kc = kc.at[rows, slot].set(k.reshape(B, -1))
+    vc = vc.at[rows, slot].set(v.reshape(B, -1))
+    pos_b = pos[:, None] if ragged else pos
+    valid = (cpos >= 0) & (cpos <= pos_b)
+    if window is not None:
+        valid &= cpos > pos_b - window
+    bias = jnp.where(valid, 0.0, L.NEG_INF).astype(jnp.float32)
+    bias = bias[:, None, None, None] if ragged else bias
+    KV, Dh = k.shape[2:]
+    qr = q.reshape(B, 1, KV, -1, Dh)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qr, kc.reshape(B, W, KV, Dh),
+                   preferred_element_type=jnp.float32) / np.sqrt(Dh) + bias
+    probs = jax.nn.softmax(s, axis=-1).astype(vc.dtype)
+    o = jnp.einsum("bkgqs,bskd->bqkgd", probs, vc.reshape(B, W, KV, Dh),
+                   preferred_element_type=jnp.float32)
+    o = o.reshape(q.shape).astype(vc.dtype)
+    return h + jnp.einsum("bshk,hkd->bsd", o, p["wo"]), kc, vc
+
+
+def _oracle_decode_step(cfg, params, cache, tokens, pos, ctx_len):
+    """Write-then-attend decode, layer by layer in Python (dense, hybrid
+    and audio families)."""
+    W = min(cfg.sliding_window or ctx_len, ctx_len)
+    window, slot = cfg.sliding_window, jnp.mod(pos, W)
+    ragged = jnp.ndim(pos) == 1
+    cache = jax.tree_util.tree_map(lambda a: a, cache)
+    rows = jnp.arange(tokens.shape[0]) if ragged else slice(None)
+    cache["positions"] = cache["positions"].at[
+        (rows, slot) if ragged else slot].set(pos)
+    cpos = cache["positions"]
+    h = M._embed(cfg, params, tokens[:, None])
+    ring = cache["attn"] if cfg.family == "hybrid" else cache["self"]
+    ks, vs = list(ring["k"]), list(ring["v"])
+    blocks = params["blocks"]
+    for i in range(len(ks)):
+        p = (params["shared_attn"] if cfg.family == "hybrid"
+             else jax.tree_util.tree_map(lambda a: a[i], blocks["attn"]))
+        h, ks[i], vs[i] = _oracle_attn(cfg, p, h, ks[i], vs[i], cpos, pos,
+                                       window, slot)
+        if cfg.family == "hybrid":
+            h = M._apply_mlp(cfg, params["shared_mlp"], h)
+            per = cfg.attn_every
+            for j in range(i * per, (i + 1) * per):
+                pl = jax.tree_util.tree_map(lambda a: a[j], blocks["mamba"])
+                h, cs, ss = M._decode_mamba(cfg, pl, h, cache["ssm"]["conv"][j],
+                                            cache["ssm"]["ssd"][j])
+                cache["ssm"]["conv"] = cache["ssm"]["conv"].at[j].set(cs)
+                cache["ssm"]["ssd"] = cache["ssm"]["ssd"].at[j].set(ss)
+            continue
+        pb = jax.tree_util.tree_map(lambda a: a[i], blocks)
+        if cfg.family == "audio":
+            h = M._decode_cross(cfg, pb["cross"], h, cache["cross_k"][i],
+                                cache["cross_v"][i])
+        h = M._apply_mlp(cfg, pb["mlp"], h)
+    ring.update(k=jnp.stack(ks), v=jnp.stack(vs))
+    h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
+    return M._unembed(cfg, params, h)[:, 0], cache
+
+
+def _prefilled(cfg, params, prompt_lens, ctx, ragged):
+    """A cache after prefill: one batch for the lock-step path, or per-row
+    prefills of different lengths stacked for the ragged path."""
+    from repro.models import prefill
+    B = len(prompt_lens)
+    batch = _batch(cfg, B=B, S=max(prompt_lens))
+    if not ragged:
+        S = prompt_lens[0]
+        batch = {k: (v[:, :S] if k == "tokens" else v) for k, v in batch.items()}
+        return prefill(cfg, params, batch, ctx_len=ctx)[1]
+    rows = [prefill(cfg, params, {"tokens": batch["tokens"][b:b + 1, :n]},
+                    ctx_len=ctx)[1] for b, n in enumerate(prompt_lens)]
+    return jax.tree_util.tree_map(
+        lambda *a: (jnp.stack(a) if a[0].ndim == 1 else
+                    jnp.concatenate(a, axis=1)), *rows)
+
+
+@pytest.mark.parametrize("name,ragged,window", [
+    ("qwen2-0.5b", False, None),
+    ("qwen2-0.5b", True, None),
+    ("qwen3-8b", False, 4),
+    ("qwen3-8b", True, 4),
+    ("zamba2-7b", False, None),
+    ("seamless-m4t-large-v2", False, None),
+])
+def test_decode_step_matches_write_then_attend(name, ragged, window):
+    """The step attends over the ring as it stands plus its own k/v and
+    writes the new rows after the layer loop; each step gives the logits
+    of writing first and attending over the whole ring, and the same
+    cache: the written rows, and every other ring row untouched.  With a
+    4-row window the ring wraps, so the row being overwritten (an old
+    position) must stay out of the softmax."""
+    cfg = get_arch(name).reduced().with_(remat="none", sliding_window=window,
+                                         dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    ctx, B = 16, 2
+    lens = [5, 7] if ragged else [6, 6]
+    cache = _prefilled(cfg, params, lens, ctx, ragged)
+    pos = np.asarray(lens, np.int32) if ragged else np.int32(lens[0])
+    ring_key = "attn" if cfg.family == "hybrid" else "self"
+    W = cache[ring_key]["k"].shape[2]
+    new = jax.jit(lambda c, t, p: decode_step(cfg, params, c, t, p, ctx))
+    old = jax.jit(lambda c, t, p: _oracle_decode_step(cfg, params, c, t, p,
+                                                      ctx))
+    toks = jax.random.randint(jax.random.PRNGKey(5), (8, B), 0, cfg.vocab)
+    for t in range(8):
+        lg, got = new(cache, toks[t], jnp.asarray(pos))
+        lg_ref, want = old(cache, toks[t], jnp.asarray(pos))
+        np.testing.assert_allclose(lg, lg_ref, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(got["positions"], want["positions"])
+        written = np.zeros((B, W), bool)
+        written[np.arange(B), np.asarray(pos) % W] = True
+        for kv in ("k", "v"):
+            g, w = np.asarray(got[ring_key][kv]), np.asarray(want[ring_key][kv])
+            before = np.asarray(cache[ring_key][kv])
+            np.testing.assert_array_equal(g[:, ~written], before[:, ~written])
+            np.testing.assert_array_equal(g[0], w[0])   # layer 0: same inputs
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(got):
+            if path[0].key not in (ring_key, "positions"):
+                ref = want
+                for p in path:
+                    ref = ref[p.key]
+                np.testing.assert_allclose(leaf, ref, rtol=1e-5, atol=1e-5)
+        cache, pos = got, pos + 1
+    if window is not None:        # the ring wrapped before the first step
+        assert min(lens) > W

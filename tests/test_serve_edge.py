@@ -114,7 +114,9 @@ def test_generate_compiles_once_across_calls():
         assert srv.jit_serve_step()._cache_size() == n_traces, \
             "second generate() retraced the serve step"
     finally:
-        monitoring.clear_event_listeners()
+        # only this listener: clearing them all would drop repro.obs's
+        # compile listener for every later test in the process
+        monitoring.unregister_event_duration_listener(listener)
 
 
 def test_generate_threads_sampling_key_across_calls():
