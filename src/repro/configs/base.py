@@ -43,6 +43,19 @@ class ArchConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 128
     attn_every: int = 0            # hybrid: shared attn block every k ssm layers
+    # one period of a stack of mixed layers, a letter a layer: "M" a Mamba-2
+    # mixer, "A" attention, each followed by the MLP when d_ff > 0; the
+    # stack repeats it n_layers / len times.  "" = the family's one kind
+    layer_pattern: str = ""
+
+    # granite's scalars: h0 = embedding_multiplier · E[tok]; every
+    # sub-block adds residual_multiplier × its output; attention scores are
+    # q·k · attention_multiplier (None: 1/√d_head); logits / logits_scaling
+    rope: bool = True              # False: no positional encoding (NoPE)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     # encoder-decoder (audio)
     enc_layers: int = 0
@@ -87,8 +100,11 @@ class ArchConfig:
         return dataclasses.replace(self, **kw)
 
     def reduced(self) -> "ArchConfig":
-        """Smoke variant of the same family: 2 layers, d_model ≤ 512, ≤4 experts."""
-        d = min(self.d_model, 256)
+        """Smoke variant of the same family: 2 layers, d_model ≤ 512, ≤4 experts.
+        A stack with a layer pattern keeps one whole period (every kind of
+        layer in its ratio) at d_model 128, 4 heads over 2 KV heads, and
+        every multiplier."""
+        d = min(self.d_model, 128 if self.layer_pattern else 256)
         dh = 32
         nh = max(self.n_heads * d // self.d_model, 2)
         nh = min(max(nh, 2), d // dh)
@@ -114,6 +130,9 @@ class ArchConfig:
                       ssm_chunk=16)
         if self.attn_every:
             kw.update(attn_every=1)   # 2 layers → 2 shared-attn insertions
+        if self.layer_pattern:
+            kw.update(n_layers=len(self.layer_pattern), n_heads=4,
+                      n_kv_heads=2)
         if self.enc_layers:
             kw.update(enc_layers=2)
         if self.n_patches:

@@ -10,6 +10,7 @@ from .mamba2_370m import CONFIG as mamba2_370m
 from .seamless_m4t_large_v2 import CONFIG as seamless_m4t_large_v2
 from .pixtral_12b import CONFIG as pixtral_12b
 from .qwen3_8b import CONFIG as qwen3_8b
+from .granite_4_0_h_micro import CONFIG as granite_4_0_h_micro
 
 ARCHS = {
     c.name: c
@@ -24,6 +25,7 @@ ARCHS = {
         seamless_m4t_large_v2,
         pixtral_12b,
         qwen3_8b,
+        granite_4_0_h_micro,
     )
 }
 

@@ -85,6 +85,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..models import model as M
+from ..models.specs import Spec
 from ..obs import CompileWatch, span
 from .admission import AdmissionPolicy, AdmissionTrace, parse_admission
 from .sharding import Rules, DEFAULT_RULES, sharded_trace, tree_shardings
@@ -330,6 +331,24 @@ class SlotServer:
         self._prefill_jits = {}       # prompt_len -> jitted batch-1 prefill
         self._tap_sink = None         # per-run host consumer of tap rows
         self._zero_poison = None      # cached all-false (K, S) fault mask
+        if recorder is not None:
+            for kind, n in self.pool_bytes().items():
+                recorder.count(f"slot_pool_bytes.{kind}", n)
+
+    def pool_bytes(self) -> dict:
+        """Bytes the slot pool's cache holds, by kind: the K/V rings
+        (``kv``) and the recurrent SSD and conv state (``ssm_state``); the
+        per-slot positions are neither."""
+        out = {"kv": 0, "ssm_state": 0}
+        specs = M.cache_specs(self.cfg, self.slots.n_slots,
+                              self.slots.ctx_len, ragged=True)
+        for path, s in jax.tree_util.tree_leaves_with_path(
+                specs, is_leaf=lambda x: isinstance(x, Spec)):
+            top = path[0].key
+            if top != "positions":
+                kind = "ssm_state" if top.startswith("ssm") else "kv"
+                out[kind] += int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
+        return out
 
     # ---- shardings ---------------------------------------------------------
     def param_shardings(self):

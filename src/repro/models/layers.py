@@ -57,7 +57,12 @@ def _mask_bias(q_pos, k_pos, causal: bool, window: Optional[int]):
     return jnp.where(ok, 0.0, NEG_INF).astype(F32)
 
 
-def _sdpa(q, k, v, bias):
+def _scaled(scores, D, scale):
+    """Scores times ``scale``, or over √D where no scale is given."""
+    return scores / np.sqrt(D) if scale is None else scores * scale
+
+
+def _sdpa(q, k, v, bias, scale=None):
     """q: (B,Sq,H,D), k/v: (B,Sk,KV,D), bias: (Sq,Sk) or (B,1,Sq,Sk).
 
     Operands stay bf16 with f32 accumulation (preferred_element_type) — an
@@ -68,7 +73,7 @@ def _sdpa(q, k, v, bias):
     q = q.reshape(B, Sq, KV, G, D)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k,
                         preferred_element_type=F32)
-    scores = scores / np.sqrt(D)
+    scores = _scaled(scores, D, scale)
     if bias.ndim == 2:
         scores = scores + bias[None, None, None]
     else:
@@ -80,14 +85,17 @@ def _sdpa(q, k, v, bias):
 
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
-              q_offset: int = 0, chunk_q: int = 512, dense_max: int = 1024):
-    """Self/cross attention with GQA.  Chunked over query blocks when long."""
+              q_offset: int = 0, chunk_q: int = 512, dense_max: int = 1024,
+              scale: Optional[float] = None):
+    """Self/cross attention with GQA.  Chunked over query blocks when long.
+    Scores are q·k · ``scale`` (default 1/√D)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     q_pos = jnp.arange(Sq) + q_offset
     k_pos = jnp.arange(Sk)
     if max(Sq, Sk) <= dense_max or Sq < 2 * chunk_q:
-        return _sdpa(q, k, v, _mask_bias(q_pos, k_pos, causal, window))
+        return _sdpa(q, k, v, _mask_bias(q_pos, k_pos, causal, window),
+                     scale)
 
     n_chunks = Sq // chunk_q
     rem = Sq - n_chunks * chunk_q
@@ -103,7 +111,7 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
         if window is not None:
             ok &= k_pos[None, :] > qp[:, None] - window
         bias = jnp.where(ok, 0.0, NEG_INF).astype(F32)
-        return _sdpa(q_blk, k, v, bias)
+        return _sdpa(q_blk, k, v, bias, scale)
 
     def body(_, q_blk_i):
         q_blk, i = q_blk_i
@@ -113,14 +121,16 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
     out = jnp.moveaxis(out, 0, 1).reshape(B, n_chunks * chunk_q, H, D)
     if rem:
         tail = _sdpa(q[:, -rem:], k, v,
-                     _mask_bias(q_pos[-rem:], k_pos, causal, window))
+                     _mask_bias(q_pos[-rem:], k_pos, causal, window), scale)
         out = jnp.concatenate([out, tail], axis=1)
     return out
 
 
 def decode_attention(q, k_cache, v_cache, cache_positions, pos, k_new, v_new,
-                     slot, window: Optional[int] = None):
-    """One-token attention vs a ring-buffer cache plus the token's own k/v.
+                     slot, window: Optional[int] = None,
+                     scale: Optional[float] = None):
+    """One-token attention vs a ring-buffer cache plus the token's own k/v;
+    scores are q·k · ``scale`` (default 1/√D).
 
     q: (B,1,H,D); caches: (B,W,KV·D), the heads folded into the minor dim;
     cache_positions: (W,) int32 holding the absolute position stored in
@@ -170,12 +180,12 @@ def decode_attention(q, k_cache, v_cache, cache_positions, pos, k_new, v_new,
     qr = q.reshape(B, Sq, KV, G, D)
     eye = jnp.eye(KV, dtype=q.dtype)[:, None, :, None]    # (KV,1,KV,1)
     qx = (qr[..., None, :] * eye).reshape(B, Sq, KV, G, KV * D)
-    scores = jnp.einsum("bqkgc,bsc->bkgqs", qx, k_cache,
-                        preferred_element_type=F32) / np.sqrt(D)
+    scores = _scaled(jnp.einsum("bqkgc,bsc->bkgqs", qx, k_cache,
+                                preferred_element_type=F32), D, scale)
     scores = scores + bias[:, None, None]             # (B|1,1,1,Sq,W)
     scores = shard_activation(scores, ("batch", None, None, None, "ctx"))
-    own = jnp.einsum("bqkgd,bqkd->bkgq", qr, k_new,
-                     preferred_element_type=F32)[..., None] / np.sqrt(D)
+    own = _scaled(jnp.einsum("bqkgd,bqkd->bkgq", qr, k_new,
+                             preferred_element_type=F32)[..., None], D, scale)
     m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), own)
     p = jnp.exp(scores - m)
     p_own = jnp.exp(own - m)

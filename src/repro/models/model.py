@@ -1,8 +1,12 @@
 """Model zoo composition: param specs, train forward, prefill, decode.
 
-One code path per family (dense / moe / ssm / hybrid / audio / vlm), all
-built from ``layers.py`` blocks, all scan-over-layers (stacked weights) so
-the lowered HLO stays compact at 64–81 layers.
+Every stack is built from ``layers.py`` blocks and scanned (stacked
+weights) so the lowered HLO stays compact at 64–81 layers.  Dense, MoE,
+SSM, VLM and layer-pattern hybrids share one description (``_layout``): a
+scan over periods of layers, each layer a mixer (attention or Mamba-2)
+followed by the family's MLP, MoE or nothing.  Two stacks keep layouts of
+their own: a shared attention block between Mamba layers (zamba2) and the
+encoder-decoder (audio).
 
 Conventions
 -----------
@@ -107,22 +111,31 @@ def _mamba_specs(cfg: ArchConfig, stacked: Optional[int]):
 
 def param_specs(cfg: ArchConfig) -> dict:
     d, V = cfg.d_model, cfg.vocab
+    # A multiplied embedding (granite: × 12) drawn at the law's 0.02 makes
+    # the input 12× louder than any other model's, and with random weights
+    # the tied head then returns the input token, by a wide margin, at
+    # nearly every position.  The fan-in law of the one-hot map it is
+    # (1/√vocab) keeps the multiplied input near the usual scale.
+    embed_init = "normal" if cfg.embedding_multiplier == 1.0 else "fan_in"
     specs: dict = {
-        "embed": Spec((V, d), ("vocab", "embed"), "normal"),
+        "embed": Spec((V, d), ("vocab", "embed"), embed_init),
         "final_norm": Spec((d,), ("embed",), "ones"),
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = Spec((d, V), ("embed", "vocab"), "fan_in")
 
     nl = cfg.n_layers
-    if cfg.family in ("dense", "vlm"):
-        specs["blocks"] = {"attn": _attn_specs(cfg, nl),
-                           "mlp": _mlp_specs(cfg, nl, cfg.d_ff)}
-    elif cfg.family == "moe":
-        specs["blocks"] = {"attn": _attn_specs(cfg, nl),
-                           "moe": _moe_specs(cfg, nl)}
-    elif cfg.family == "ssm":
-        specs["blocks"] = {"mamba": _mamba_specs(cfg, nl)}
+    lay = _layout(cfg)
+    if lay is not None:
+        period, ffn = lay
+        n = nl // len(period)
+        mixer = {"attn": _attn_specs, "mamba": _mamba_specs}
+        specs["blocks"] = {k: mixer[k](cfg, n * period.count(k))
+                           for k in dict.fromkeys(period)}
+        if ffn == "mlp":
+            specs["blocks"]["mlp"] = _mlp_specs(cfg, nl, cfg.d_ff)
+        elif ffn == "moe":
+            specs["blocks"]["moe"] = _moe_specs(cfg, nl)
     elif cfg.family == "hybrid":
         g = cfg.n_layers // cfg.attn_every
         rem = cfg.n_layers - g * cfg.attn_every
@@ -169,6 +182,13 @@ def n_active_params(cfg: ArchConfig) -> int:
 # block applications (sequence / train)
 # ============================================================================
 
+def _residual(cfg, h, u):
+    """h + residual_multiplier · u: every sub-block's output joins the
+    residual stream through here."""
+    r = cfg.residual_multiplier
+    return h + (u if r == 1.0 else u * r)
+
+
 def _apply_attn(cfg, p, h, *, causal=True, positions=None, kv_h=None,
                 window=None, return_kv=False):
     """Standard pre-norm attention block.  kv_h: cross-attention memory."""
@@ -182,16 +202,19 @@ def _apply_attn(cfg, p, h, *, causal=True, positions=None, kv_h=None,
     if cfg.qk_norm:
         q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if kv_h is None and positions is not None:       # rope only on self-attn
+    if kv_h is None and positions is not None and cfg.rope:  # self-attn only
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
     if cfg.use_flash_attention:
         from ..kernels.ops import flash_attention
+        if cfg.attention_multiplier is not None:
+            raise NotImplementedError("the flash kernel scales by 1/√d_head")
         o = flash_attention(q, k, v, causal=causal and kv_h is None,
                             window=window)
     else:
-        o = L.attention(q, k, v, causal=causal and kv_h is None, window=window)
-    out = h + jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        o = L.attention(q, k, v, causal=causal and kv_h is None, window=window,
+                        scale=cfg.attention_multiplier)
+    out = _residual(cfg, h, jnp.einsum("bshk,hkd->bsd", o, p["wo"]))
     if return_kv:
         return out, (k, v)
     return out
@@ -199,7 +222,7 @@ def _apply_attn(cfg, p, h, *, causal=True, positions=None, kv_h=None,
 
 def _apply_mlp(cfg, p, h):
     x = L.rms_norm(h, p["norm"], cfg.norm_eps)
-    return h + L.swiglu(x, p["w_gate"], p["w_up"], p["w_down"])
+    return _residual(cfg, h, L.swiglu(x, p["w_gate"], p["w_up"], p["w_down"]))
 
 
 def _apply_moe(cfg, p, h):
@@ -209,7 +232,7 @@ def _apply_moe(cfg, p, h):
     if "shared" in p:
         sp = p["shared"]
         y = y + L.swiglu(x, sp["w_gate"], sp["w_up"], sp["w_down"])
-    return h + y, aux
+    return _residual(cfg, h, y), aux
 
 
 def _mamba_inner(cfg, p, x_n):
@@ -241,7 +264,7 @@ def _apply_mamba(cfg, p, h, return_state=False):
     y = y.reshape(B, S, di).astype(h.dtype)
     y = L.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(h.dtype),
                    p["gate_norm"], cfg.norm_eps)
-    out = h + jnp.einsum("bse,ed->bsd", y, p["out_proj"])
+    out = _residual(cfg, h, jnp.einsum("bse,ed->bsd", y, p["out_proj"]))
     if return_state:
         K = cfg.ssm_conv
         conv_state = conv_in[:, S - (K - 1):, :]
@@ -292,26 +315,121 @@ def _scan(fn, stacked_params, h, remat: bool):
     return h
 
 
+_MIXERS = {"A": "attn", "M": "mamba"}
+_SCOPES = {"attn": "mixer.attention", "mamba": "mixer.mamba"}
+
+
+def _layout(cfg):
+    """The stack as a scan over periods of layers: ``(period, ffn)``, where
+    ``period`` names one period's mixer per layer ("attn" | "mamba") and
+    ``ffn`` the block after every mixer ("mlp" | "moe" | None).  A dense,
+    MoE or SSM stack is a period of one layer.  None for the stacks that
+    keep layouts of their own: a shared attention block between Mamba
+    layers (zamba2) and the encoder-decoder (audio)."""
+    if cfg.family == "audio" or cfg.attn_every:
+        return None
+    pattern = cfg.layer_pattern or ("M" if cfg.family == "ssm" else "A")
+    if cfg.n_layers % len(pattern):
+        raise ValueError(f"{cfg.name}: {cfg.n_layers} layers are not whole "
+                         f"periods of {pattern!r}")
+    ffn = "moe" if cfg.n_experts else ("mlp" if cfg.d_ff else None)
+    return tuple(_MIXERS[c] for c in pattern), ffn
+
+
+def _apply_mixer(cfg, kind, p, h, *, positions, window, return_state=False):
+    """A layer's mixer over a sequence; with ``return_state`` also what
+    decode continues from (an attention layer's k/v, a Mamba layer's conv
+    and SSD state)."""
+    with jax.named_scope(_SCOPES[kind]):
+        if kind == "attn":
+            return _apply_attn(cfg, p, h, positions=positions, window=window,
+                               return_kv=return_state)
+        return _apply_mamba(cfg, p, h, return_state=return_state)
+
+
+def _apply_ffn(cfg, ffn, p, h):
+    """The block after a mixer: (h, the MoE's aux loss or 0)."""
+    if ffn == "mlp":
+        with jax.named_scope("mlp"):
+            return _apply_mlp(cfg, p, h), 0.0
+    if ffn == "moe":
+        with jax.named_scope("moe"):
+            return _apply_moe(cfg, p, h)
+    return h, 0.0
+
+
+def _scan_periods(cfg, stacks, carry, layer, *, remat=False, pin=False):
+    """Run ``layer`` over the stack, one scan step a period.
+
+    ``stacks`` maps each mixer kind to a tree whose leaves stack that kind's
+    layers (weights, and for decode the layer's cache), and the ffn kind to
+    the ffn weights of every layer.  ``layer(kind, i, p, p_ffn, carry)``
+    applies one layer, ``i`` its (traced) index in its kind's stack, and
+    returns ``(carry, out)``.  Each layer is one remat unit; ``pin``
+    re-pins the carry's batch sharding after every layer (the train
+    stack).  Returns ``(carry, {kind: outs stacked over that kind's
+    layers})``.
+
+    A kind with one layer a period is scanned over as it is stacked.  A
+    kind with several is indexed per layer by its traced index: slicing a
+    whole period's block out of the stack would copy it (the block has a
+    consumer per layer), where one layer's slice fuses into its matmul."""
+    period, ffn = _layout(cfg)
+    n = cfg.n_layers // len(period)
+    per = {k: period.count(k) for k in dict.fromkeys(period)}
+    if ffn is not None:
+        per[ffn] = len(period)
+    xs = {k: stacks[k] for k, m in per.items() if m == 1}
+    fns = {}
+    for k in dict.fromkeys(period):
+        f = partial(layer, k)
+        fns[k] = jax.checkpoint(f) if remat else f
+
+    def weights(k, pg, i):
+        if per[k] == 1:
+            return pg[k]
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), stacks[k])
+
+    def body(c, inp):
+        pg, q = inp
+        outs = {k: [] for k in dict.fromkeys(period)}
+        for j, k in enumerate(period):
+            i = q * per[k] + len(outs[k])
+            pf = (weights(ffn, pg, q * len(period) + j) if ffn is not None
+                  else None)
+            c, y = fns[k](i, weights(k, pg, i), pf, c)
+            if pin:
+                c = _constrain_carry(c)
+            outs[k].append(y)
+        return c, {k: v[0] if per[k] == 1 else
+                   jax.tree_util.tree_map(lambda *a: jnp.stack(a), *v)
+                   for k, v in outs.items()}
+
+    carry, ys = jax.lax.scan(body, carry, (xs, jnp.arange(n)))
+    return carry, {k: jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), v) if per[k] > 1 else v
+        for k, v in ys.items()}
+
+
 def _decoder_stack(cfg, params, h, positions, *, window=None, memory=None):
     remat = cfg.remat == "full"
     blocks = params["blocks"]
-    if cfg.family in ("dense", "vlm"):
-        def f(p, x):
-            x = _apply_attn(cfg, p["attn"], x, positions=positions, window=window)
-            return _apply_mlp(cfg, p["mlp"], x)
-        return _scan(f, blocks, h, remat), 0.0
-    if cfg.family == "moe":
-        def f(p, carry):
-            x, aux = carry
-            x = _apply_attn(cfg, p["attn"], x, positions=positions, window=window)
-            x, a = _apply_moe(cfg, p["moe"], x)
-            return (x, aux + a)
-        (h, aux) = _scan(f, blocks, (h, jnp.zeros((), jnp.float32)), remat)
-        return h, aux / cfg.n_layers
-    if cfg.family == "ssm":
-        def f(p, x):
-            return _apply_mamba(cfg, p["mamba"], x)
-        return _scan(f, blocks, h, remat), 0.0
+    lay = _layout(cfg)
+    if lay is not None:
+        ffn = lay[1]
+
+        def layer(kind, i, p, pf, c):
+            x, aux = c
+            x = _apply_mixer(cfg, kind, p, x, positions=positions,
+                             window=window)
+            x, a = _apply_ffn(cfg, ffn, pf, x)
+            return (x, aux + a if ffn == "moe" else aux), None
+
+        aux0 = jnp.zeros((), jnp.float32) if ffn == "moe" else None
+        (h, aux), _ = _scan_periods(cfg, blocks, (h, aux0), layer,
+                                    remat=remat, pin=True)
+        return h, (aux / cfg.n_layers if ffn == "moe" else 0.0)
     if cfg.family == "hybrid":
         g = cfg.n_layers // cfg.attn_every
         k = cfg.attn_every
@@ -365,7 +483,9 @@ def _encoder_stack(cfg, params, frames):
 # ============================================================================
 
 def _embed(cfg, params, tokens):
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+    h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+    m = cfg.embedding_multiplier
+    return h if m == 1.0 else h * m
 
 
 def _unembed(cfg, params, h):
@@ -373,6 +493,8 @@ def _unembed(cfg, params, h):
         logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
     else:
         logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return _shard_act(logits, ("batch", "seq", "vocab"))
 
 
@@ -461,29 +583,22 @@ def prefill(cfg: ArchConfig, params, batch, ctx_len: Optional[int] = None):
     positions = jnp.arange(S)
     cache: dict = {}
     fam = cfg.family
+    lay = _layout(cfg)
 
-    if fam in ("dense", "vlm", "moe"):
-        def f(x, p):
-            if fam == "moe":
-                x, kv = _apply_attn(cfg, p["attn"], x, positions=positions,
-                                    window=window, return_kv=True)
-                x, _ = _apply_moe(cfg, p["moe"], x)
-            else:
-                x, kv = _apply_attn(cfg, p["attn"], x, positions=positions,
-                                    window=window, return_kv=True)
-                x = _apply_mlp(cfg, p["mlp"], x)
-            return x, kv
+    if lay is not None:
+        ffn = lay[1]
 
-        h, (ks, vs) = jax.lax.scan(f, h, params["blocks"])
-        kc, vc, posbuf = _ring_from_seq(ks, vs, W)
-        cache = {"self": {"k": kc, "v": vc}, "positions": posbuf}
-    elif fam == "ssm":
-        def f(x, p):
-            x, st = _apply_mamba(cfg, p["mamba"], x, return_state=True)
-            return x, st
+        def layer(kind, i, p, pf, x):
+            x, st = _apply_mixer(cfg, kind, p, x, positions=positions,
+                                 window=window, return_state=True)
+            return _apply_ffn(cfg, ffn, pf, x)[0], st
 
-        h, (cs, ss) = jax.lax.scan(f, h, params["blocks"])
-        cache = {"ssm": {"conv": cs, "ssd": ss}}
+        h, st = _scan_periods(cfg, params["blocks"], h, layer)
+        if "attn" in st:
+            kc, vc, posbuf = _ring_from_seq(*st["attn"], W)
+            cache = {"self": {"k": kc, "v": vc}, "positions": posbuf}
+        if "mamba" in st:
+            cache["ssm"] = dict(zip(("conv", "ssd"), st["mamba"]))
     elif fam == "hybrid":
         g = cfg.n_layers // cfg.attn_every
         k = cfg.attn_every
@@ -589,11 +704,15 @@ def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int, *,
         }
 
     c: dict = {}
-    if cfg.family in ("dense", "vlm", "moe"):
-        c["self"] = ring(nl)
-        c["positions"] = pos_spec
-    elif cfg.family == "ssm":
-        c["ssm"] = ssm_states(nl)
+    lay = _layout(cfg)
+    if lay is not None:
+        period = lay[0]
+        n = nl // len(period)
+        if "attn" in period:
+            c["self"] = ring(n * period.count("attn"))
+            c["positions"] = pos_spec
+        if "mamba" in period:
+            c["ssm"] = ssm_states(n * period.count("mamba"))
     elif cfg.family == "hybrid":
         g = cfg.n_layers // cfg.attn_every
         rem = cfg.n_layers - g * cfg.attn_every
@@ -642,15 +761,16 @@ def _decode_attn(cfg, p, h, kc, vc, cache_positions, pos, window, slot):
     if cfg.qk_norm:
         q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    ragged = jnp.ndim(pos) == 1
-    posv = pos[:, None] if ragged else jnp.full((1,), pos)
-    q = L.rope(q, posv, cfg.rope_theta)
-    k = L.rope(k, posv, cfg.rope_theta)
+    if cfg.rope:
+        ragged = jnp.ndim(pos) == 1
+        posv = pos[:, None] if ragged else jnp.full((1,), pos)
+        q = L.rope(q, posv, cfg.rope_theta)
+        k = L.rope(k, posv, cfg.rope_theta)
     o = L.decode_attention(q, kc, vc, cache_positions, pos, k, v, slot,
-                           window=window)
+                           window=window, scale=cfg.attention_multiplier)
     B = k.shape[0]
-    return (h + jnp.einsum("bshk,hkd->bsd", o, p["wo"]), k.reshape(B, -1),
-            v.reshape(B, -1))
+    return (_residual(cfg, h, jnp.einsum("bshk,hkd->bsd", o, p["wo"])),
+            k.reshape(B, -1), v.reshape(B, -1))
 
 
 def _write_rows(ring, k, v, slot):
@@ -697,7 +817,8 @@ def _decode_mamba(cfg, p, h, conv_state, ssd_state):
     y = y.reshape(B, 1, di).astype(h.dtype)
     y = L.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(h.dtype),
                    p["gate_norm"], cfg.norm_eps)
-    return h + jnp.einsum("bse,ed->bsd", y, p["out_proj"]), conv_state, ssd_state
+    return (_residual(cfg, h, jnp.einsum("bse,ed->bsd", y, p["out_proj"])),
+            conv_state, ssd_state)
 
 
 def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
@@ -709,7 +830,9 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
     ``cache_specs(..., ragged=True)`` — the positions buffer is then
     (B, W) and every row writes its own ring slot.
     The layer loop only reads the K/V rings; it returns the step's new rows,
-    which one ``_write_rows`` per ring stores after the loop.
+    which one ``_write_rows`` per ring stores after the loop.  The recurrent
+    state stacks ride in the loop's carry and each Mamba layer updates its
+    own slice in place, so a step holds one copy of them.
     Returns (logits (B, V), new_cache).
     """
     W = min(cfg.sliding_window or ctx_len, ctx_len)
@@ -731,29 +854,40 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
         cpos = cache["positions"]
 
     fam = cfg.family
-    if fam in ("dense", "vlm", "moe"):
-        def f(x, inp):
-            p, kc, vc = inp
-            x, k, v = _decode_attn(cfg, p["attn"], x, kc, vc, cpos, pos,
-                                   window, slot)
-            if fam == "moe":
-                x, _ = _apply_moe(cfg, p["moe"], x)
-            else:
-                x = _apply_mlp(cfg, p["mlp"], x)
-            return x, (k, v)
+    lay = _layout(cfg)
+    if lay is not None:
+        ffn = lay[1]
+        stacks = dict(params["blocks"])
+        if "attn" in stacks:
+            stacks["attn"] = (stacks["attn"], cache["self"]["k"],
+                              cache["self"]["v"])
+        states = ((cache["ssm"]["conv"], cache["ssm"]["ssd"])
+                  if "ssm" in cache else None)
 
-        h, (ks, vs) = jax.lax.scan(
-            f, h, (params["blocks"], cache["self"]["k"], cache["self"]["v"]))
-        cache["self"] = _write_rows(cache["self"], ks, vs, slot)
-    elif fam == "ssm":
-        def f(x, inp):
-            p, cs, ss = inp
-            x, cs, ss = _decode_mamba(cfg, p["mamba"], x, cs, ss)
-            return x, (cs, ss)
+        def layer(kind, i, p, pf, c):
+            x, st = c
+            y = None
+            with jax.named_scope(_SCOPES[kind]):
+                if kind == "attn":
+                    pa, kc, vc = p
+                    x, k, v = _decode_attn(cfg, pa, x, kc, vc, cpos, pos,
+                                           window, slot)
+                    y = (k, v)
+                else:
+                    conv, ssd = st
+                    x, cs, ss = _decode_mamba(
+                        cfg, p, x, jax.lax.dynamic_index_in_dim(conv, i, 0,
+                                                                False),
+                        jax.lax.dynamic_index_in_dim(ssd, i, 0, False))
+                    st = (jax.lax.dynamic_update_index_in_dim(conv, cs, i, 0),
+                          jax.lax.dynamic_update_index_in_dim(ssd, ss, i, 0))
+            return (_apply_ffn(cfg, ffn, pf, x)[0], st), y
 
-        h, (cs, ss) = jax.lax.scan(
-            f, h, (params["blocks"], cache["ssm"]["conv"], cache["ssm"]["ssd"]))
-        cache["ssm"] = {"conv": cs, "ssd": ss}
+        (h, states), ys = _scan_periods(cfg, stacks, (h, states), layer)
+        if "attn" in ys:
+            cache["self"] = _write_rows(cache["self"], *ys["attn"], slot)
+        if states is not None:
+            cache["ssm"] = dict(zip(("conv", "ssd"), states))
     elif fam == "hybrid":
         g = cfg.n_layers // cfg.attn_every
         k = cfg.attn_every

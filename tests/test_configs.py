@@ -15,6 +15,7 @@ ASSIGNED = {
     "seamless-m4t-large-v2": ("audio", 24, 1024, 16, 16, 8192, 256206),
     "pixtral-12b": ("vlm", 40, 5120, 32, 8, 14336, 131072),
     "qwen3-8b": ("dense", 36, 4096, 32, 8, 12288, 151936),
+    "granite-4.0-h-micro": ("hybrid", 40, 2048, 32, 8, 8192, 100352),
 }
 
 
@@ -42,6 +43,9 @@ def test_assigned_details():
     assert get_arch("mamba2-370m").ssm_state == 128
     assert get_arch("seamless-m4t-large-v2").enc_layers == 24
     assert get_arch("pixtral-12b").n_patches > 0
+    g = get_arch("granite-4.0-h-micro")
+    assert g.layer_pattern == "MMMMMAMMMM" and not g.rope
+    assert g.ssm_state == 128 and g.ssm_chunk == 256 and g.tie_embeddings
 
 
 def test_assigned_shapes():
@@ -54,8 +58,11 @@ def test_assigned_shapes():
 
 @pytest.mark.parametrize("name", sorted(ASSIGNED))
 def test_reduced_variants_within_smoke_budget(name):
-    r = get_arch(name).reduced()
-    assert r.n_layers <= 2 or r.family == "hybrid" and r.n_layers <= 2
+    c = get_arch(name)
+    r = c.reduced()
+    # a layer pattern keeps one whole period, every kind of layer in it
+    assert r.n_layers <= max(2, len(c.layer_pattern))
+    assert r.layer_pattern == c.layer_pattern
     assert r.d_model <= 512
     if r.n_experts:
         assert r.n_experts <= 4

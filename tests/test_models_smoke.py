@@ -167,7 +167,7 @@ def test_sliding_window_attention_restricts_context():
 
 @pytest.mark.parametrize("name", ["qwen3-8b", "mamba2-370m", "zamba2-7b",
                                   "seamless-m4t-large-v2", "deepseek-moe-16b",
-                                  "pixtral-12b"])
+                                  "pixtral-12b", "granite-4.0-h-micro"])
 def test_prefill_then_decode_matches_full_forward(name):
     """prefill(prompt) + decode(next tokens) ≡ forward over the whole seq."""
     from repro.models import prefill
