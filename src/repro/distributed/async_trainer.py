@@ -15,6 +15,14 @@ Mapping of the paper onto a synchronous SPMD pod:
 * the fused delayed-update (server step, eq. 2) is the Pallas
   ``async_update`` kernel's target on TPU; here it is the optimizer apply.
 
+Rows computed: group g owns rows [g·B/n, (g+1)·B/n) of the round's batch.
+Called with a ``groups`` row (the plan's ids of the round's participants,
+padded with groups whose mask is 0), the step gathers those groups' rows
+and runs forward and backward on them alone; the rows' weights are the
+full batch's, gathered alike.  Without it, or where gathering would change
+the loss or its sharding (:meth:`AsyncTrainer.step_rows`), every row is
+computed and the mask zeroes the rest.
+
 ``delay_rounds = 0`` recovers synchronous SGD (the paper's baseline).
 """
 from __future__ import annotations
@@ -76,8 +84,12 @@ class AsyncTrainer:
         self.opt = opt
         self.async_cfg = async_cfg
         self.rules = rules
-        self.n_groups = int(np.prod([mesh.shape[a] for a in rules.data_axes
-                                     if a in mesh.axis_names])) or 1
+        #: data-parallel shards of the mesh; ``n_groups`` starts there and
+        #: callers may set more (virtual) groups
+        self.data_shards = int(np.prod([mesh.shape[a]
+                                        for a in rules.data_axes
+                                        if a in mesh.axis_names])) or 1
+        self.n_groups = self.data_shards
         self.update_impl = resolve_update_impl(opt.update_impl)
         #: pooled impls flatten the whole state into per-dtype pool buffers
         #: ONCE here (layout is static per arch × mesh); the update is then
@@ -235,15 +247,31 @@ class AsyncTrainer:
         return tree_shardings(pspecs, self.mesh, self.rules, zero=True)
 
     def _example_weights(self, mask, batch_size: int):
-        """mask (n_groups,) → per-example weights (B,): group g owns the
-        contiguous slice [g·B/n, (g+1)·B/n)."""
+        """mask (n_groups,) → per-example weights (B,) over the whole
+        batch: group g owns the contiguous slice [g·B/n, (g+1)·B/n).  A
+        step that computes only some groups' rows gathers these weights
+        with the same row indices as the rows themselves."""
         per = batch_size // self.n_groups
         return jnp.repeat(mask, per, total_repeat_length=batch_size)
+
+    def step_rows(self, batch_size: int, width: int) -> int:
+        """Rows the step computes for a batch of ``batch_size`` rows when
+        at most ``width`` groups take part in a round: those groups' rows,
+        or the whole batch.  The whole batch where every group may take
+        part, where the config has experts (the MoE aux loss covers every
+        row, weighted or not) and where the gathered rows would not split
+        evenly over the mesh's data shards."""
+        rows = width * (batch_size // self.n_groups)
+        if width >= self.n_groups or self.cfg.n_experts or \
+                rows % self.data_shards:
+            return batch_size
+        return rows
 
     def train_step_fn(self):
         """The pjit train step.
 
-        ``step(state, batch, mask, delay_scale=None, grad_density=None)``:
+        ``step(state, batch, mask, delay_scale=None, grad_density=None,
+        fault_gain=None, groups=None)``:
         ``delay_scale`` is the optional per-round stepsize scale
         (γ_q = γ·delay_scale_q) fed from the realised schedule's delay
         metadata (:func:`repro.core.round_delay_scales`); omitted, the
@@ -253,6 +281,10 @@ class AsyncTrainer:
         each gradient leaf keeps only its largest-magnitude ``density``
         fraction (per-leaf quantile threshold — the density is traced, so
         k is dynamic and ``top_k`` is unavailable); 1.0 is an exact no-op.
+        ``groups`` is the optional ``(G,)`` int32 row of the plan's
+        participating group ids: where :meth:`step_rows` allows it, the
+        forward and backward run on those groups' rows of ``batch`` only
+        (module docstring); the masks, metrics and update are unchanged.
         Sparsification happens BEFORE the ZeRO reshard / pooling, i.e. on
         the gradient the server update consumes.  With
         ``delay_rounds > 0`` the whole server update (eq. 2) — consume the
@@ -274,7 +306,7 @@ class AsyncTrainer:
                                     pooled_pspec(self.mesh, self.rules))
 
         def step(state, batch, mask, delay_scale=None, grad_density=None,
-                 fault_gain=None):
+                 fault_gain=None, groups=None):
             if self.pooled:
                 params = unpool_tree(
                     self.pool_layout,
@@ -284,6 +316,14 @@ class AsyncTrainer:
                 params = state["params"]
             bsz = batch["tokens"].shape[0]
             w = self._example_weights(mask.astype(jnp.float32), bsz)
+            if groups is not None and \
+                    self.step_rows(bsz, groups.shape[0]) < bsz:
+                per = bsz // self.n_groups
+                rows = (groups[:, None] * per
+                        + jnp.arange(per, dtype=groups.dtype)).reshape(-1)
+                batch = jax.tree_util.tree_map(lambda x: x[rows], batch)
+                w = w[rows]
+                bsz = rows.shape[0]
             if fault_gain is not None:
                 # fault channel: multiplicative gain on the round's RECEIVED
                 # contribution (huge = inflated corrupted receipt, NaN =
@@ -491,14 +531,16 @@ class AsyncTrainer:
     def jit_train_step(self, batch_shape, donate: bool = True,
                        with_delay_scale: bool = False,
                        with_grad_density: bool = False,
-                       with_fault_gain: bool = False):
+                       with_fault_gain: bool = False,
+                       with_groups: bool = False):
         """pjit-compiled train step for a (batch, seq) shape.
 
         The compiled signature is exactly positional: ``step(state, batch,
         mask)`` plus one replicated traced extra per enabled channel, in
         the fixed order ``delay_scale`` (per-round stepsize scale), then
         ``grad_density`` (per-round gradient keep-density), then
-        ``fault_gain`` (per-worker loss-weight gains) — each present only
+        ``fault_gain`` (per-worker loss-weight gains), then ``groups``
+        (the round's participating group ids) — each present only
         when its ``with_*`` flag is on, the remaining channels pinned to
         None inside (so e.g. density-without-scale leaves the trainer's
         static stepsize rule in charge)."""
@@ -509,7 +551,8 @@ class AsyncTrainer:
         step = self.train_step_fn()
         names = [n for n, on in (("delay_scale", with_delay_scale),
                                  ("grad_density", with_grad_density),
-                                 ("fault_gain", with_fault_gain)) if on]
+                                 ("fault_gain", with_fault_gain),
+                                 ("groups", with_groups)) if on]
 
         def fn_(state, batch, mask, *extras):
             return step(state, batch, mask, **dict(zip(names, extras)))

@@ -86,6 +86,9 @@ class ExecStats:
     * ``tripped_round`` — round at which the divergence breaker tripped
       through the tap lane (None = never tripped / no breaker): the run
       stopped launching after the chunk containing it.
+    * ``rows_computed`` — batch rows put through forward and backward in
+      the run (rounds × :attr:`PlanExecutor.step_rows`, times the grid
+      points on the γ-grid lane).
     """
 
     launches: int = 0
@@ -93,6 +96,7 @@ class ExecStats:
     tap_events: int = 0
     snapshots: int = 0
     tripped_round: Optional[int] = None
+    rows_computed: int = 0
 
 
 @dataclasses.dataclass
@@ -219,6 +223,12 @@ class PlanExecutor:
         self.recorder = recorder      # repro.obs.Recorder | None
         self.watch = CompileWatch(recorder)   # retrace sentinel over the jits
         self._batch_of = make_batch_fn(plan, trainer.cfg)
+        #: rows each round's step computes; below the batch only where the
+        #: plan carries a ``groups`` channel and the trainer can gather
+        #: (otherwise the channel stays off the program altogether)
+        self.step_rows = plan.global_batch if plan.groups is None else \
+            trainer.step_rows(plan.global_batch, plan.groups.shape[1])
+        self._gather = self.step_rows < plan.global_batch
         self._repl = NamedSharding(trainer.mesh, P())   # plan slices
         self._step = trainer.train_step_fn()
         self._eager = None            # lazily built parity-oracle pair
@@ -255,7 +265,9 @@ class PlanExecutor:
         Scenario channels ride the same xs dict: ``xs["cdf"]`` (data-drift
         phase index) feeds the batch synthesiser, ``xs["dens"]``
         (keep-density) feeds the step's sparsifier, ``xs["gain"]``
-        (per-worker fault gains) feeds the step's fault channel.
+        (per-worker fault gains) feeds the step's fault channel, and
+        ``xs["groups"]`` (the round's participating group ids) tells the
+        step which rows to compute.
         """
         import jax
 
@@ -263,6 +275,7 @@ class PlanExecutor:
         with_density = self.plan.grad_density is not None
         with_gain = self.plan.fault_gain is not None
         with_scale = self.plan.adaptive or force_scale or with_density
+        gather = self._gather
 
         def body(st, xs):
             batch = jax.tree_util.tree_map(
@@ -275,6 +288,8 @@ class PlanExecutor:
                 kw["grad_density"] = xs["dens"]
             if with_gain:
                 kw["fault_gain"] = xs["gain"]
+            if gather:
+                kw["groups"] = xs["groups"]
             st, m = step(st, batch, xs["mask"], **kw)
             return st, m
 
@@ -372,6 +387,8 @@ class PlanExecutor:
             xs["dens"] = jnp.asarray(self.plan.grad_density[lo:hi])
         if self.plan.fault_gain is not None:
             xs["gain"] = jnp.asarray(self.plan.fault_gain[lo:hi])
+        if self._gather:
+            xs["groups"] = jnp.asarray(self.plan.groups[lo:hi])
         return xs
 
     def _maybe_snapshot(self, snapshot, hi: int, state, stats) -> None:
@@ -397,14 +414,19 @@ class PlanExecutor:
                                             None) is None:
             snapshot.recorder = rec
 
-    def _record_stats(self, stats: "ExecStats", rounds: int) -> None:
-        """Fold the run's dispatch accounting into the obs counters (and
-        let the retrace sentinel stamp any compile events it missed)."""
+    def _record_stats(self, stats: "ExecStats", rounds: int,
+                      lanes: int = 1) -> None:
+        """Count the rows the run computed (``lanes`` γ-grid points per
+        round), then fold the run's dispatch accounting into the obs
+        counters (and let the retrace sentinel stamp any compile events it
+        missed)."""
+        stats.rows_computed = rounds * lanes * self.step_rows
         rec = self.recorder
         if rec is None:
             return
         self.watch.observe()
         rec.count("rounds", rounds)
+        rec.count("rows_computed", stats.rows_computed)
         rec.count("launches", stats.launches)
         rec.count("host_syncs", stats.host_syncs)
         rec.count("tap_events", stats.tap_events)
@@ -684,7 +706,7 @@ class PlanExecutor:
         if snapshot is not None:
             snapshot.drain()
         all_ms = np.concatenate(rows, axis=1) if rows else None
-        self._record_stats(stats, last_hi - start_round)
+        self._record_stats(stats, last_hi - start_round, lanes=g)
         return ExecResult(
             state=states,
             metrics=({} if all_ms is None else
@@ -714,7 +736,8 @@ class PlanExecutor:
                     donate=self.donate,
                     with_delay_scale=with_scale,
                     with_grad_density=with_density,
-                    with_fault_gain=with_gain)))
+                    with_fault_gain=with_gain,
+                    with_groups=self._gather)))
         batch_of, step = self._eager
         rec = self.recorder
         rows = []
@@ -730,6 +753,8 @@ class PlanExecutor:
                 args += (jnp.float32(plan.grad_density[i]),)
             if with_gain:
                 args += (jnp.asarray(plan.fault_gain[i]),)
+            if self._gather:
+                args += (jnp.asarray(plan.groups[i]),)
             with span(rec, "launch", "executor", lo=i, hi=i + 1):
                 state, m = step(*args)
             stats.launches += 1

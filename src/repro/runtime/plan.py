@@ -75,6 +75,12 @@ class RunPlan:
       gains from the fault transforms (``repro.faults``): 1.0 neutral,
       huge-but-finite = corrupted receipt, NaN = poisoned receipt.  Only
       participating workers' gains matter (the mask zeroes the rest).
+
+    ``groups`` — ``(rounds, G)`` int32 ids of the groups whose rows the
+    step computes, read off ``masks`` (:func:`participating_groups`, never
+    given), and ``None`` unless every round leaves a group out.  The train
+    step gathers those groups' rows from the synthesised batch and runs
+    forward and backward on them alone.
     """
 
     masks: np.ndarray
@@ -91,6 +97,7 @@ class RunPlan:
     cdf_index: Optional[np.ndarray] = None
     grad_density: Optional[np.ndarray] = None
     fault_gain: Optional[np.ndarray] = None
+    groups: Optional[np.ndarray] = dataclasses.field(init=False)
 
     @property
     def rounds(self) -> int:
@@ -103,6 +110,16 @@ class RunPlan:
     @property
     def vocab(self) -> int:
         return int(self.token_cdf.shape[0])
+
+    @property
+    def step_rows(self) -> int:
+        """Rows a round's step computes where the trainer can gather them
+        (:attr:`repro.runtime.PlanExecutor.step_rows` says what it does):
+        the ``groups`` width's rows, or the whole batch where some round
+        takes every group."""
+        if self.groups is None:
+            return self.global_batch
+        return self.groups.shape[1] * (self.global_batch // self.n_groups)
 
     @property
     def n_grid(self) -> int:
@@ -166,6 +183,7 @@ class RunPlan:
                 raise ValueError(
                     "fault_gain must not contain zeros — drop workers via "
                     "the availability channel, not a zero gain")
+        object.__setattr__(self, "groups", participating_groups(self.masks))
 
     # ------------------------------------------------------------------ views
     def device_slices(self, lo: int = 0, hi: Optional[int] = None):
@@ -195,7 +213,22 @@ class RunPlan:
                 "n_cdf_phases": (0 if self.cdf_bank is None
                                  else int(self.cdf_bank.shape[0])),
                 "sparsified": self.grad_density is not None,
-                "faulted": self.fault_gain is not None}
+                "faulted": self.fault_gain is not None,
+                "step_rows": self.step_rows}
+
+
+def participating_groups(masks: np.ndarray) -> Optional[np.ndarray]:
+    """``(rounds, G)`` int32 ids of each round's groups with a nonzero
+    mask, padded with groups whose mask is 0 that round, where G (the most
+    participants of any round, at least 1) is below the group count; None
+    where some round takes every group."""
+    part = np.asarray(masks) != 0
+    width = max(int(part.sum(axis=1).max(initial=0)), 1)
+    if width >= part.shape[1]:
+        return None
+    # participants first, then the round's idle groups, each in id order
+    order = np.argsort(~part, axis=1, kind="stable")
+    return order[:, :width].astype(np.int32)
 
 
 def fold_data_keys(seed: int, rounds: int) -> np.ndarray:

@@ -145,3 +145,61 @@ def test_moe_arch_trains():
         state, m = step(state, batch, jnp.ones((tr.n_groups,)))
     assert np.isfinite(float(m["loss"]))
     assert float(m["aux"]) > 0
+
+
+def _round_masks(scheduler: str, b: int, rounds: int, seed: int = 0):
+    sched = make_scheduler(scheduler, 4, b=b, seed=seed) if b > 1 else \
+        make_scheduler(scheduler, 4, seed=seed)
+    tm = TimingModel(heterogeneous_speeds(4, slow_factor=6.0), "poisson",
+                     seed=seed)
+    return round_masks(build_schedule(sched, tm, rounds * b), rounds)
+
+
+@pytest.mark.parametrize("arch,masks_of", [
+    ("qwen2-0.5b", "shuffled"),
+    ("mamba2-370m", "shuffled"),
+    ("qwen2-0.5b", "waiting"),          # a worker returns twice: weight 2
+    ("qwen2-0.5b", "elastic"),          # a round whose groups all dropped
+])
+def test_gathered_step_matches_full_batch(arch, masks_of):
+    """Gathering the participating groups' rows is the same step as the
+    full batch with the other rows weighted 0: loss, applied gradient norm
+    and the whole state after 3 rounds, computed in float32."""
+    from repro.runtime.plan import participating_groups
+
+    cfg = get_arch(arch).reduced().with_(remat="none", dtype="float32")
+    tr = AsyncTrainer(cfg, _mesh(), opt=OptConfig(lr=1e-2, clip_norm=1.0),
+                      async_cfg=AsyncConfig(delay_rounds=1))
+    tr.n_groups = 4
+    if masks_of == "waiting":
+        masks = _round_masks("fedbuff", 2, 12)
+        last = int(np.argmax(masks.max(axis=1) == 2))
+        assert masks[last].max() == 2 and last >= 2
+        masks = masks[last - 2:last + 1]
+    else:
+        masks = _round_masks("shuffled", 1, 3)
+        if masks_of == "elastic":
+            masks[1] = 0
+    groups = participating_groups(masks)
+    assert groups is not None and groups.shape[1] < 4
+    step = jax.jit(tr.train_step_fn())
+    full = gathered = tr.init_state(jax.random.PRNGKey(0))
+    for q in range(3):
+        batch = _batch(cfg, B=8, seed=q)
+        mask = jnp.asarray(masks[q])
+        full, m_full = step(full, batch, mask)
+        gathered, m_g = step(gathered, batch, mask,
+                             groups=jnp.asarray(groups[q]))
+        for k in ("loss", "ce", "grad_norm", "participation"):
+            np.testing.assert_allclose(float(m_g[k]), float(m_full[k]),
+                                       rtol=1e-5, atol=1e-7,
+                                       err_msg=f"round {q} {k}")
+    if masks_of == "elastic":
+        assert float(m_full["grad_norm"]) == 0.0     # round 1 applied 0
+    for a, b in zip(jax.tree_util.tree_leaves(gathered),
+                    jax.tree_util.tree_leaves(full)):
+        # params and the delayed buffer are bf16: a gradient's last f32
+        # digit may round an element one bf16 ulp (2^-7) apart, and the
+        # later rounds run from there; wrong rows would be O(1) apart
+        a32, b32 = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a32 - b32) <= 2.0 ** -7 * np.linalg.norm(b32)

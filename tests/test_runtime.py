@@ -19,6 +19,9 @@ Covered here:
   curves, with honest ExecStats (launches / host_syncs / tap_events),
 * the vmapped γ-grid lane: ``run_grid[i]`` ≡ a single-γ scan run on a
   trainer built at γ_i,
+* the ``groups`` channel: read off the masks, counted in
+  ``rows_computed``, and absent from the program wherever the step keeps
+  the whole batch,
 * chunk-boundary edge cases: ``rounds_per_launch`` of 1, ``rounds``, and a
   ragged ``rounds % K != 0`` split, plus ``on_step`` barrier semantics,
 * checkpoint-resume at a chunk boundary (pooled state) ≡ uninterrupted,
@@ -28,6 +31,8 @@ Covered here:
   self-bootstrap on single-device hosts, mirroring
   tests/test_pool_multidevice.py).
 """
+import dataclasses
+import hashlib
 import os
 import subprocess
 import sys
@@ -216,12 +221,87 @@ def test_scan_matches_eager(scheduler, impl, adaptive, delay_rounds):
     assert r_e.tap_events == 0
     assert r_s.launches == 2 and r_s.host_syncs == 1
     assert r_s.tap_events == 0
+    # both lanes computed only the participating groups' rows
+    assert plan.groups is not None
+    assert r_s.stats.rows_computed == r_e.stats.rows_computed \
+        == 6 * plan.step_rows
     for k in METRICS:
         np.testing.assert_allclose(r_s.metrics[k], r_e.metrics[k], **TOL,
                                    err_msg=f"metric {k}")
     if adaptive:        # the adaptive lowering actually ran (the rule may
         assert plan.adaptive     # still saturate at 1 for short horizons)
         assert np.all(plan.delay_scales <= 1.0)
+
+
+# ---------------------------------------------------------------------------
+# rows computed: the plan's groups channel
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("scheduler,rows", [
+    ("shuffled", 2),            # one group of 2 rows a round
+    ("minibatch:b=4", 8),       # every group takes part: the whole batch
+])
+def test_step_rows_are_read_off_the_masks_and_counted(scheduler, rows):
+    from repro.obs import Recorder
+
+    job = _job()
+    plan = _plan_for(_spec(job, scheduler=scheduler, T=6), job)
+    assert plan.summary()["step_rows"] == rows
+    assert (plan.groups is None) == (rows == job.global_batch)
+    if plan.groups is not None:
+        # each round's participants are among its computed groups
+        for q in range(plan.rounds):
+            assert set(np.flatnonzero(plan.masks[q])) <= \
+                set(plan.groups[q].tolist())
+    tr = _trainer(job)
+    rec = Recorder()
+    r_s = run_scan(tr, plan, tr.init_state(jax.random.PRNGKey(0)),
+                   rounds_per_launch=4, recorder=rec)
+    r_e = run_eager(tr, plan, tr.init_state(jax.random.PRNGKey(0)))
+    assert r_s.stats.rows_computed == r_e.stats.rows_computed \
+        == plan.rounds * rows
+    assert rec.tracer.counters()["rows_computed"] == plan.rounds * rows
+
+
+def test_groups_channel_follows_the_masks():
+    """The channel is read off the plan's masks, also when they are
+    replaced: full participation drops it, a round with no participant
+    pads with idle groups, and the width is the busiest round's."""
+    job = _job()
+    plan = _plan_for(_spec(job, T=4), job)
+    assert plan.groups.shape == (4, 1)
+    full = dataclasses.replace(plan, masks=np.ones((4, 4), np.float32))
+    assert full.groups is None and full.step_rows == 8
+    masks = np.zeros((4, 4), np.float32)
+    masks[0, 3] = masks[2, [1, 2]] = 1.0
+    masks[3, 0] = 2.0
+    odd = dataclasses.replace(plan, masks=masks)
+    np.testing.assert_array_equal(odd.groups,
+                                  [[3, 0], [0, 1], [1, 2], [0, 1]])
+    assert odd.step_rows == 4
+
+
+def _chunk_hlo_hash(job, scheduler) -> str:
+    plan = _plan_for(_spec(job, scheduler=scheduler, T=4), job)
+    tr = _trainer(job)
+    ex = PlanExecutor(tr, plan, donate=False)
+    lowered = ex._chunk_jit("chunk").__wrapped_jit__.lower(
+        tr.init_state(jax.random.PRNGKey(0)), ex._slices(0, plan.rounds))
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize("arch,gathers", [
+    ("qwen2-0.5b", True),
+    ("deepseek-moe-16b", False),     # the aux loss covers every row
+])
+def test_full_batch_runs_lower_to_the_full_batch_program(arch, gathers):
+    """Where the step keeps the whole batch, a shuffled plan lowers to the
+    same chunk program, text for text, as a plan in which every group
+    takes part (no groups channel); plans of one shape differ in data
+    only."""
+    job = _job(arch=arch, arch_overrides=MICRO if arch == "qwen2-0.5b"
+               else ())
+    assert (_chunk_hlo_hash(job, "shuffled")
+            != _chunk_hlo_hash(job, "minibatch:b=4")) is gathers
 
 
 # ---------------------------------------------------------------------------
@@ -445,6 +525,8 @@ def test_run_grid_matches_single_gamma_runs():
                   rounds_per_launch=4)    # ragged: 4 + 2
     assert rg.metrics["loss"].shape == (4, 6)
     assert rg.launches == 2 and rg.host_syncs == 1
+    assert gplan.groups is not None        # the lane gathers rows too
+    assert rg.stats.rows_computed == 4 * 6 * gplan.step_rows
     # γ really differed across lanes
     assert not np.allclose(rg.metrics["loss"][0], rg.metrics["loss"][3],
                            rtol=1e-6)
@@ -811,21 +893,26 @@ def test_scenario_plan_scan_matches_eager():
 # ---------------------------------------------------------------------------
 @pytest.mark.skipif(not MULTI, reason="needs >= 8 devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-def test_scan_pooled_multidevice_parity():
-    """Scan executor on a 4-data × 2-model mesh with pooled ZeRO-sharded
+@pytest.mark.parametrize("data,rows", [
+    (4, 8),     # one group's 2 rows do not split over 4 shards: full batch
+    (2, 2),     # they do over 2: gathered
+])
+def test_scan_pooled_multidevice_parity(data, rows):
+    """Scan executor on a data × 2-model mesh with pooled ZeRO-sharded
     state ≡ the eager oracle on the same mesh, and the carried pools keep
     their sharding across chunk launches (donation must not silently
     replicate)."""
-    from repro.launch.mesh import _make_mesh
+    from jax.sharding import Mesh, NamedSharding
     from repro.distributed import pooled_pspec
-    from jax.sharding import NamedSharding
 
-    mesh = _make_mesh((4, 2), ("data", "model"))
+    mesh = Mesh(np.array(jax.devices()[:2 * data]).reshape(data, 2),
+                ("data", "model"))
     job = _job(update_impl="pallas_pooled_interpret")
     spec = _spec(job, T=4)
     plan = _plan_for(spec, job)
     tr = _trainer(job, mesh=mesh)
-    assert tr.pool_layout.n_shards == 4
+    assert tr.pool_layout.n_shards == data
+    assert PlanExecutor(tr, plan).step_rows == rows
 
     r_e = run_eager(tr, plan, tr.init_state(jax.random.PRNGKey(0)))
     r_s = run_scan(tr, plan, tr.init_state(jax.random.PRNGKey(0)),
