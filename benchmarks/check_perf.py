@@ -1,17 +1,15 @@
 """CI gate: fail on a dispatch-layer perf regression vs the committed
-baseline (``benchmarks/BENCH_runtime.json`` / ``benchmarks/BENCH_serve.json``).
+baseline (e.g. ``benchmarks/BENCH_runtime.json``).
 
 Absolute rounds/s across heterogeneous CI hosts is pure noise — a GitHub
 runner and the laptop that wrote the baseline differ by far more than any
 real regression.  What IS machine-portable is each row's throughput
-normalised by the SAME payload's reference row — the eager row for the
-``runtime_dispatch_ab`` kind, the lock-step serving row for the
-``serve_slots`` kind: that ratio isolates the dispatch/metric-transport
-layer (launch amortisation, readback barriers, tap overhead, slot-loop
-bookkeeping) from raw core speed, which is exactly what these benches
-exist to track.  The gate fails when any subject row's normalised
-throughput (or the grid lane's ``grid_speedup``, or the slot lane's
-``occupancy``) drops more than ``--tolerance`` (default 30%) below the
+normalised by the SAME payload's eager row (the ``runtime_dispatch_ab``
+kind): that ratio isolates the dispatch/metric-transport layer (launch
+amortisation, readback barriers, tap overhead) from raw core speed, which
+is exactly what these benches exist to track.  The gate fails when any
+subject row's normalised throughput (or the grid lane's
+``grid_speedup``) drops more than ``--tolerance`` (default 30%) below the
 baseline's.  The ``faults`` and ``obs`` kinds instead gate an ABSOLUTE
 same-machine ratio (guarded/unguarded, traced/untraced) against a
 documented ceiling — see their checkers.
@@ -27,8 +25,6 @@ Usage::
 
     python benchmarks/check_perf.py experiments/figs/BENCH_runtime.json \
         benchmarks/BENCH_runtime.json --tolerance 0.3
-    python benchmarks/check_perf.py experiments/figs/BENCH_serve.json \
-        benchmarks/BENCH_serve.json
 """
 from __future__ import annotations
 
@@ -99,72 +95,6 @@ def check_runtime(current: dict, baseline: dict, tolerance: float,
                 failures.append(
                     f"{key}: grid_speedup {g_cur:.3f} < floor "
                     f"{g_floor:.3f}")
-    return failures
-
-
-def _serve_rows(payload: dict, path: str = "<payload>") -> tuple[dict, float]:
-    """mode-key -> entry, plus the lock-step tok/s normaliser."""
-    lock = [e for e in payload["entries"] if e["mode"] == "lockstep"]
-    if not lock:
-        raise SystemExit(
-            f"bench file {path!r} has no lockstep row to normalise against")
-    rows = {}
-    for e in payload["entries"]:
-        key = (e["mode"] if e["mode"] == "lockstep"
-               else (e["mode"], e["n_slots"], e.get("admission", "pure")))
-        rows[key] = e
-    return rows, float(lock[0]["tok_per_s"])
-
-
-def check_serve(current: dict, baseline: dict, tolerance: float,
-                paths=("<current>", "<baseline>")) -> list:
-    """Slot-serving gate: tok/s normalised by the same run's lock-step
-    row (machine-portable), plus the realised slot occupancy — that one
-    is a deterministic function of the admission bookkeeping, so a drop
-    means the slot loop is leaving lanes idle, not that the host is slow."""
-    cur_rows, cur_lock = _serve_rows(current, paths[0])
-    base_rows, base_lock = _serve_rows(baseline, paths[1])
-    failures = []
-    print(f"{'row':<34} {'base':>8} {'now':>8} {'floor':>8}  verdict")
-    for key, base in sorted(base_rows.items(), key=str):
-        if key == "lockstep":
-            continue                      # the normaliser, not a subject
-        cur = cur_rows.get(key)
-        if cur is None:
-            failures.append(
-                f"{key}: present in baseline {paths[1]!r} but missing "
-                f"from current payload {paths[0]!r}")
-            print(f"{str(key):<34} {'':>8} {'':>8} {'':>8}  MISSING")
-            continue
-        base_n = float(base["tok_per_s"]) / base_lock
-        cur_n = float(cur["tok_per_s"]) / cur_lock
-        floor = base_n * (1.0 - tolerance)
-        ok = cur_n >= floor
-        print(f"{str(key):<34} {base_n:>8.3f} {cur_n:>8.3f} "
-              f"{floor:>8.3f}  {'ok' if ok else 'REGRESSION'}")
-        if not ok:
-            failures.append(
-                f"{key}: normalised tok/s {cur_n:.3f} < floor "
-                f"{floor:.3f} (baseline {base_n:.3f}, "
-                f"tolerance {tolerance:.0%})")
-        if "occupancy" in base:
-            if "occupancy" not in cur:
-                failures.append(
-                    f"{key}: baseline has occupancy but the current row "
-                    "lacks the field")
-                print(f"{'  occupancy':<34} "
-                      f"{float(base['occupancy']):>8.3f} {'':>8} "
-                      f"{'':>8}  MISSING")
-                continue
-            o_base = float(base["occupancy"])
-            o_cur = float(cur["occupancy"])
-            o_floor = o_base * (1.0 - tolerance)
-            o_ok = o_cur >= o_floor
-            print(f"{'  occupancy':<34} {o_base:>8.3f} {o_cur:>8.3f} "
-                  f"{o_floor:>8.3f}  {'ok' if o_ok else 'REGRESSION'}")
-            if not o_ok:
-                failures.append(
-                    f"{key}: occupancy {o_cur:.3f} < floor {o_floor:.3f}")
     return failures
 
 
@@ -329,7 +259,6 @@ def check_resilience(current: dict, baseline: dict, tolerance: float,
 #: bench kinds this gate knows how to compare (payload "bench" field)
 CHECKERS = {
     "runtime_dispatch_ab": check_runtime,
-    "serve_slots": check_serve,
     "faults": check_faults,
     "obs": check_obs,
     "resilience": check_resilience,

@@ -13,7 +13,8 @@ drawn from a seed, and checks what comes out:
   compiled Mosaic update kernels (``tpu_custom_call`` in the step's HLO);
   per-round losses match the reference phase.
 * serve: 8 requests through the 4-slot server; every request completes and
-  the greedy tokens are compared with the lock-step server's.
+  the greedy tokens are compared with a plain loop of the model's prefill
+  and decode step.
 
 ``--chips 4`` runs only the pooled train phase on a (data=2, model=2) mesh
 of four chips, and the same spec on one of them as the comparison.
@@ -193,27 +194,56 @@ def check_losses_agree(label: str, want, got) -> None:
         raise AssertionError(f"{label}: losses differ: {want} vs {got}")
 
 
-def serve_phase(*, reduced: bool = False, mesh=None) -> dict:
-    """8 requests through the 4-slot server, against the lock-step one."""
-    from repro.api import ExperimentSpec, ServeBackend, ServeJob
+def plain_loop_tokens(cfg, params, prompts, T: int, ctx: int) -> np.ndarray:
+    """Greedy tokens from ``M.prefill`` and ``M.decode_step`` alone: each
+    prompt prefilled alone (batch 1, as the slot server admits it), the
+    rows stacked into one cache, then T − 1 steps over every row at once."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import model as M
 
-    common = dict(arch=ARCH, reduced=reduced, prompt_len=PROMPT_LEN,
-                  batch=N_REQUESTS)
+    @jax.jit
+    def pf(params, tokens):
+        logits, cache = M.prefill(cfg, params, {"tokens": tokens},
+                                  ctx_len=ctx)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    step = jax.jit(lambda params, c, t, p: M.decode_step(cfg, params, c, t,
+                                                         p, ctx))
+    rows = [pf(params, jnp.asarray(p[None])) for p in prompts]
+    tok = jnp.concatenate([t for t, _ in rows])
+    cache = jax.tree_util.tree_map_with_path(
+        lambda path, *a: jnp.concatenate(
+            a, axis=0 if path[0].key == "positions" else 1),
+        *[c for _, c in rows])
+    pos = jnp.full(tok.shape, prompts.shape[1], jnp.int32)
+    out = [np.asarray(tok)]
+    for _ in range(T - 1):
+        logits, cache = step(params, cache, tok, pos)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        out.append(np.asarray(tok))
+        pos = pos + 1
+    return np.stack(out, axis=1)
+
+
+def serve_phase(*, reduced: bool = False, mesh=None) -> dict:
+    """8 requests through the 4-slot server, against the plain loop."""
+    import jax
+    from repro.api import ExperimentSpec, ServeBackend, ServeJob
+    from repro.models import init_params
+
     slot_spec = ExperimentSpec(objective=ServeJob(
-        **common, n_slots=N_SLOTS, n_requests=N_REQUESTS,
-        steps_per_launch=8), T=GEN, seed=SEED)
-    lock_spec = ExperimentSpec(objective=ServeJob(**common), T=GEN,
-                               seed=SEED)
+        arch=ARCH, reduced=reduced, prompt_len=PROMPT_LEN, batch=N_REQUESTS,
+        n_slots=N_SLOTS, n_requests=N_REQUESTS, steps_per_launch=8),
+        T=GEN, seed=SEED)
     runs = {}
-    for name, spec in (("slot", slot_spec), ("slot_warm", slot_spec),
-                       ("lockstep", lock_spec)):
-        res = ServeBackend(mesh=mesh).run(spec)
+    for name in ("slot", "slot_warm"):
+        res = ServeBackend(mesh=mesh).run(slot_spec)
         runs[name] = res
         log("serve", run=name, seconds=round(res.seconds, 3),
             decode_s=round(res.extra["decode_seconds"], 3),
             tokens=list(res.x.shape), peak_bytes_in_use=_peak_bytes())
-    slot, lock = runs["slot"], runs["lockstep"]
-    warm = runs["slot_warm"]
+    slot, warm = runs["slot"], runs["slot_warm"]
     log("serve", n_slots=N_SLOTS, n_requests=N_REQUESTS,
         prompt_len=PROMPT_LEN, gen=GEN,
         cold_minus_warm_s=round(slot.seconds - warm.seconds, 3),
@@ -223,23 +253,26 @@ def serve_phase(*, reduced: bool = False, mesh=None) -> dict:
         occupancy=round(float(slot.extra["occupancy"]), 4),
         evictions=slot.extra["evictions"], timeouts=slot.extra["timeouts"])
 
-    toks, ref = np.asarray(slot.x), np.asarray(lock.x)
+    toks = np.asarray(slot.x)
     if toks.shape != (N_REQUESTS, GEN) or (toks < 0).any():
         raise AssertionError(f"serve: incomplete requests: {toks}")
     if slot.extra["evictions"] or slot.extra["timeouts"]:
         raise AssertionError("serve: evictions or timeouts on a clean run")
     if not np.array_equal(toks, warm.x):
         raise AssertionError("serve: two identical serves disagree")
+    cfg = slot_spec.objective.make_arch()
+    prompts = slot.extra["prompts"]
+    ref = plain_loop_tokens(cfg, init_params(cfg, jax.random.PRNGKey(SEED)),
+                            prompts, GEN, PROMPT_LEN + GEN)
     same = [bool(np.array_equal(a, b)) for a, b in zip(toks, ref)]
-    log("serve", requests_bitwise_equal_lockstep=f"{sum(same)}/{len(same)}")
+    log("serve", requests_bitwise_equal_plain_loop=f"{sum(same)}/{len(same)}")
     for r in np.flatnonzero(~np.asarray(same)):
-        _check_near_tie(slot_spec, lock.extra["prompts"][r], ref[r], toks[r],
-                        r)
-    return {"tokens": toks, "lockstep": ref, "matches": sum(same)}
+        _check_near_tie(slot_spec, prompts[r], ref[r], toks[r], r)
+    return {"tokens": toks, "plain_loop": ref, "matches": sum(same)}
 
 
 def _check_near_tie(spec, prompt, ref_row, got_row, r: int) -> None:
-    """Where the slot and lock-step tokens part, the two tokens' logits
+    """Where the slot and plain-loop tokens part, the two tokens' logits
     (from a full forward over the common prefix) agree within bf16."""
     import jax
     import jax.numpy as jnp
@@ -254,8 +287,8 @@ def _check_near_tie(spec, prompt, ref_row, got_row, r: int) -> None:
     last = np.asarray(logits[0, -1], np.float32)
     a, b = float(last[ref_row[t]]), float(last[got_row[t]])
     log("serve", request=r, first_differing_step=t,
-        lockstep_token=int(ref_row[t]), slot_token=int(got_row[t]),
-        logit_lockstep=a, logit_slot=b, logit_max=float(last.max()))
+        plain_loop_token=int(ref_row[t]), slot_token=int(got_row[t]),
+        logit_plain_loop=a, logit_slot=b, logit_max=float(last.max()))
     if not np.isclose(a, b, rtol=LOGIT_TOL, atol=LOGIT_TOL):
         raise AssertionError(
             f"serve: request {r} step {t}: logits {a} vs {b} differ "

@@ -1,6 +1,8 @@
-"""Batched serving example: prefill a batch of prompts, then decode with the
-ring-buffer KV cache through ``repro.api``'s serve backend (which drives the
-Server's sharded, cache-donating jitted step).
+"""Batched serving example: ``batch`` prompts through ``repro.api``'s serve
+backend, which serves every job through the continuous-batching
+``SlotServer`` (here ``batch`` requests in ``batch`` slots: each prompt is
+prefilled alone, then all of them decode together over the ring-buffer KV
+cache in one compiled loop).
 
   PYTHONPATH=src python examples/serve_batched.py [--arch qwen2-0.5b]
 """

@@ -11,7 +11,7 @@
   rounds per XLA launch, ``runtime="eager"`` is the per-round parity
   oracle.  Same schedulers as the simulator, identical ordering by
   construction.
-* :class:`ServeBackend` — batched decoding through ``distributed.Server``.
+* :class:`ServeBackend` — continuous batching through ``distributed.SlotServer``.
 
 All three return a :class:`RunResult`.
 """
@@ -25,7 +25,6 @@ import numpy as np
 
 from ..core import delay_adaptive_stepsizes, replay, replay_grid, round_masks
 from ..core.trace import summarize
-from ..obs import span
 from ..runtime import compile_plan, execute
 from .result import RunResult
 from .spec import ExperimentSpec, ServeJob, StepsizePolicy, TrainJob
@@ -398,14 +397,12 @@ class TrainerBackend:
 
 
 class ServeBackend:
-    """Prefill + batched decode through the sharded ``Server`` driver.
-
-    A :class:`ServeJob` with ``n_slots`` set routes to the slot-based
-    continuous-batching lane (:class:`repro.distributed.SlotServer`):
-    requests flow through persistent decode slots under a
-    scheduler-registry admission policy, and the realised admission trace
-    lowers to an ordinary ``Schedule`` (``extra["schedule"]`` /
-    ``extra["tau_report"]``).  The lock-step path stays the parity oracle.
+    """Serve a :class:`ServeJob` through the continuous-batching
+    :class:`repro.distributed.SlotServer`: ``n_requests`` requests flow
+    through ``n_slots`` persistent decode slots (both default to
+    ``batch``) under a scheduler-registry admission policy, and the
+    realised admission trace lowers to an ordinary ``Schedule``
+    (``extra["schedule"]`` / ``extra["tau_report"]``).
     """
 
     name = "serve"
@@ -431,46 +428,9 @@ class ServeBackend:
         return job, cfg, mesh, rules, params
 
     def run(self, spec: ExperimentSpec) -> RunResult:
-        if getattr(spec.objective, "n_slots", None):
-            return self._run_slots(spec)
-        import jax
-        import jax.numpy as jnp
-        from ..distributed import Server, ServeConfig
-        from ..models import prefill
-
-        t0 = time.time()
-        rec = self.recorder
-        job, cfg, mesh, rules, params = self._setup(spec)
-        ctx = job.prompt_len + spec.T
-        server = Server(cfg, mesh, ServeConfig(batch=job.batch, ctx_len=ctx,
-                                               temperature=job.temperature,
-                                               seed=spec.seed), rules=rules)
-        prompts = np.random.default_rng(spec.seed).integers(
-            0, cfg.vocab, (job.batch, job.prompt_len)).astype(np.int32)
-        with span(rec, "prefill", "server", batch=job.batch,
-                  plen=job.prompt_len):
-            last, cache = prefill(cfg, params,
-                                  {"tokens": jnp.asarray(prompts)},
-                                  ctx_len=ctx)
-            toks = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        t_dec = time.time()
-        with span(rec, "decode", "server", steps=spec.T - 1):
-            gen = server.generate(params, np.asarray(toks), spec.T - 1,
-                                  start_pos=job.prompt_len, cache=cache)
-        gen = np.concatenate([np.asarray(toks)[:, None], gen], axis=1)
-        dt = time.time() - t_dec
-        return RunResult(
-            spec=spec, backend=self.name, x=gen, seconds=time.time() - t0,
-            extra={"prompts": prompts, "arch": cfg.name,
-                   "decode_seconds": dt,
-                   "tok_per_s": job.batch * (spec.T - 1) / max(dt, 1e-9),
-                   "obs": rec.summary(rounds=spec.T)
-                   if rec is not None else None})
-
-    def _run_slots(self, spec: ExperimentSpec) -> RunResult:
-        """Continuous batching: ``n_requests`` requests through ``n_slots``
-        ragged decode lanes; admissions follow the job's scheduler-registry
-        policy, arrivals its timing-registry pattern."""
+        """``n_requests`` requests through ``n_slots`` decode lanes;
+        admissions follow the job's scheduler-registry policy, arrivals its
+        timing-registry pattern."""
         from ..distributed import (SlotServer, SlotConfig, draw_arrivals,
                                    parse_admission, RetryPolicy,
                                    OverloadPolicy)
@@ -479,6 +439,7 @@ class ServeBackend:
         t0 = time.time()
         job, cfg, mesh, rules, params = self._setup(spec)
         n_req = job.n_requests or job.batch
+        n_slots = job.n_slots or job.batch
         ctx = job.prompt_len + spec.T
         retry = (RetryPolicy(max_attempts=job.max_retries,
                              backoff_base=job.retry_backoff)
@@ -487,12 +448,10 @@ class ServeBackend:
                     if job.queue_cap is not None else None)
         server = SlotServer(
             cfg, mesh,
-            SlotConfig(n_slots=job.n_slots, ctx_len=ctx,
+            SlotConfig(n_slots=n_slots, ctx_len=ctx,
                        temperature=job.temperature, seed=spec.seed,
                        steps_per_launch=job.steps_per_launch),
             rules=rules, recorder=self.recorder)
-        # same prompt stream as the lock-step oracle (first batch rows
-        # coincide when n_requests == batch — the parity gate relies on it)
         prompts = np.random.default_rng(spec.seed).integers(
             0, cfg.vocab, (n_req, job.prompt_len)).astype(np.int32)
         arrivals = draw_arrivals(n_req, job.arrival, seed=spec.seed)
@@ -523,7 +482,7 @@ class ServeBackend:
             extra={"prompts": prompts, "arch": cfg.name,
                    "decode_seconds": dt,
                    "tok_per_s": n_req * (spec.T - 1) / max(dt, 1e-9),
-                   "n_slots": job.n_slots, "admission": job.admission,
+                   "n_slots": n_slots, "admission": job.admission,
                    "arrivals": arrivals, "ttft_steps": res.ttft_steps,
                    "occupancy": res.occupancy,
                    "decode_steps": res.decode_steps, "chunks": res.chunks,
@@ -536,7 +495,7 @@ class ServeBackend:
                    if self.recorder is not None else None,
                    "tau_report": tau_report(
                        res.schedule, parse_admission(job.admission)[0],
-                       concurrency=job.n_slots,
+                       concurrency=n_slots,
                        scenario_spec=job.arrival or "",
                        evictions=res.evictions,
                        timeouts=res.timeouts,

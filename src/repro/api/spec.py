@@ -151,23 +151,17 @@ class TrainJob:
 
 @dataclasses.dataclass(frozen=True)
 class ServeJob:
-    """Objective for the serve backend: batched greedy/temperature decoding.
+    """Objective for the serve backend: greedy/temperature decoding with
+    continuous batching.
 
     ``ExperimentSpec.T`` counts decode steps (per-request token budget).
-
-    Two serving modes share this job:
-
-    * lock-step (default, ``n_slots=None``) — a fixed batch decodes in
-      unison through :class:`repro.distributed.Server`; scheduler/timing
-      fields are unused.
-    * continuous batching (``n_slots`` set) — ``n_requests`` requests flow
-      through ``n_slots`` persistent decode lanes
-      (:class:`repro.distributed.SlotServer`); ``admission`` picks which
-      queued request fills a freed slot (scheduler-registry compact spec,
-      e.g. ``"pure"`` / ``"fedbuff:b=2"``) and ``arrival`` draws
-      inter-arrival gaps from the timing registry
-      (``"pattern[:gap=G]"``, e.g. ``"poisson:gap=4"``; ``None`` = all
-      requests queued at step 0).
+    ``n_requests`` requests (default ``batch``) flow through ``n_slots``
+    persistent decode lanes (default ``batch``) of
+    :class:`repro.distributed.SlotServer`; ``admission`` picks which
+    queued request fills a freed slot (scheduler-registry compact spec,
+    e.g. ``"pure"`` / ``"fedbuff:b=2"``) and ``arrival`` draws
+    inter-arrival gaps from the timing registry (``"pattern[:gap=G]"``,
+    e.g. ``"poisson:gap=4"``; ``None`` = all requests queued at step 0).
     """
 
     arch: str = "qwen2-0.5b"
@@ -176,26 +170,26 @@ class ServeJob:
     prompt_len: int = 12
     temperature: float = 0.0
     arch_overrides: tuple = ()          # ((field, value), ...)
-    n_slots: Optional[int] = None       # set → continuous-batching lane
+    n_slots: Optional[int] = None       # default: batch
     n_requests: Optional[int] = None    # default: batch
     admission: str = "pure"             # scheduler-registry compact spec
     arrival: Optional[str] = None       # timing-registry "pattern[:gap=G]"
     steps_per_launch: int = 8           # decode steps per chunk launch
-    #: queue-wait budget in decode steps (slot lane only): a request still
-    #: queued past it is timed out at the admission sweep, never admitted,
-    #: and surfaced in the result's timeout map / τ-report
+    #: queue-wait budget in decode steps: a request still queued past it
+    #: is timed out at the admission sweep, never admitted, and surfaced
+    #: in the result's timeout map / τ-report
     deadline: Optional[int] = None
-    #: retry budget (slot lane only): total admission attempts per request
+    #: retry budget: total admission attempts per request
     #: (1 = detect-and-discard); > 1 re-queues evicted/timed-out requests
     #: with exponential backoff ``retry_backoff · 2^(failures−1)`` steps
     max_retries: int = 1
     retry_backoff: int = 4              # backoff base, in decode steps
-    #: bounded admission queue (slot lane only): eligible waiters beyond
-    #: the cap are shed under ``shed_policy``
+    #: bounded admission queue: eligible waiters beyond the cap are shed
+    #: under ``shed_policy``
     queue_cap: Optional[int] = None
     shed_policy: str = "reject-new"     # "reject-new" | "drop-oldest"
-    #: graceful drain (slot lane only): stop admitting at this decode
-    #: step, finish in-flight lanes, cancel (account) the rest
+    #: graceful drain: stop admitting at this decode step, finish
+    #: in-flight lanes, cancel (account) the rest
     drain_after: Optional[int] = None
 
     def __post_init__(self):
@@ -203,21 +197,12 @@ class ServeJob:
             raise ValueError("n_slots must be >= 1")
         if self.steps_per_launch < 1:
             raise ValueError("steps_per_launch must be >= 1")
-        for knob, val in (("deadline", self.deadline),
-                          ("queue_cap", self.queue_cap),
-                          ("drain_after", self.drain_after)):
-            if val is not None and self.n_slots is None:
-                raise ValueError(
-                    f"{knob} is a slot-lane knob; set n_slots as well")
         if self.deadline is not None and self.deadline < 0:
             raise ValueError("deadline must be >= 0")
         if self.drain_after is not None and self.drain_after < 0:
             raise ValueError("drain_after must be >= 0")
         if self.max_retries < 1:
             raise ValueError("max_retries must be >= 1 (1 = no retry)")
-        if self.max_retries > 1 and self.n_slots is None:
-            raise ValueError(
-                "max_retries is a slot-lane knob; set n_slots as well")
         if self.retry_backoff < 0:
             raise ValueError("retry_backoff must be >= 0")
         # constructing the policies validates queue_cap/shed_policy too

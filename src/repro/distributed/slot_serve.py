@@ -1,16 +1,15 @@
-"""Slot-based continuous-batching serving: one compiled ragged decode loop.
+"""Slot-based continuous-batching serving: one compiled decode loop.
 
-The lock-step :class:`~repro.distributed.serve.Server` decodes a fixed
-batch where every request starts and finishes together.  This module is
-the production shape: ``n_slots`` persistent decode lanes, each carrying
-its own position / activity / budget, stepped by ONE compiled program —
-the serving analogue of the executor's per-round participation masks.
+``n_slots`` persistent decode lanes, each carrying its own position /
+activity / budget, are stepped by ONE compiled program — the serving
+analogue of the executor's per-round participation masks.  Every
+``ServeJob`` runs here (``api.ServeBackend``).
 
 Design, mirroring the repo's schedule-is-value-independent thesis:
 
-* **Device**: a chunk of ``steps_per_launch`` ragged decode steps runs as
-  a ``lax.scan`` whose body calls ``models.decode_step`` with VECTOR
-  ``pos`` (per-slot positions, ``cache_specs(..., ragged=True)``).
+* **Device**: a chunk of ``steps_per_launch`` decode steps runs as a
+  ``lax.scan`` whose body calls ``models.decode_step`` with per-slot
+  ``pos`` (the cache's positions buffer is per row as well).
   Inactive slots freeze (token/pos/remaining held by the active mask) and
   their ring re-writes are idempotent, so masking replaces control flow —
   the program never retraces as requests come and go.  Each step streams
@@ -315,7 +314,7 @@ class _Ledger:
 
 
 class SlotServer:
-    """Continuous-batching decode over ``n_slots`` ragged lanes."""
+    """Continuous-batching decode over ``n_slots`` lanes."""
 
     def __init__(self, cfg: ArchConfig, mesh: Mesh, slots: SlotConfig,
                  rules: Rules = DEFAULT_RULES, recorder=None):
@@ -341,7 +340,7 @@ class SlotServer:
         per-slot positions are neither."""
         out = {"kv": 0, "ssm_state": 0}
         specs = M.cache_specs(self.cfg, self.slots.n_slots,
-                              self.slots.ctx_len, ragged=True)
+                              self.slots.ctx_len)
         for path, s in jax.tree_util.tree_leaves_with_path(
                 specs, is_leaf=lambda x: isinstance(x, Spec)):
             top = path[0].key
@@ -357,7 +356,7 @@ class SlotServer:
     def state_shardings(self):
         S = self.slots.n_slots
         cache_sh = tree_shardings(
-            M.cache_specs(self.cfg, S, self.slots.ctx_len, ragged=True),
+            M.cache_specs(self.cfg, S, self.slots.ctx_len),
             self.mesh, self.rules)
         lane = NamedSharding(self.mesh, P(self.rules.data_axes[-1]
                                           if S > 1 else None))
@@ -371,8 +370,7 @@ class SlotServer:
         request is admitted (their writes are idempotent)."""
         S = self.slots.n_slots
         return {
-            "cache": M.init_cache(self.cfg, S, self.slots.ctx_len,
-                                  ragged=True),
+            "cache": M.init_cache(self.cfg, S, self.slots.ctx_len),
             "toks": jnp.zeros((S,), jnp.int32),
             "pos": jnp.zeros((S,), jnp.int32),
             "active": jnp.zeros((S,), bool),
@@ -408,7 +406,7 @@ class SlotServer:
     # ---- compiled programs -------------------------------------------------
     def chunk_fn(self):
         """Jitted ``chunk(params, state, idx0, poison) -> state``: K
-        ragged decode steps with per-step tap emission.  Compiled once;
+        decode steps with per-step tap emission.  Compiled once;
         ``idx0`` is a traced scalar so chunk position never retraces.
         ``poison`` is a (K, n_slots) bool fault-injection mask: flagged
         cells force that lane's logits to NaN BEFORE the finite check, so
@@ -485,19 +483,22 @@ class SlotServer:
         requests that share a lane over time."""
         if self._admit_fn is not None:
             return self._admit_fn
+        # each cache leaf's batch axis, read off its logical axes
+        batch_axis = jax.tree_util.tree_map(
+            lambda s: s.axes.index("batch"),
+            M.cache_specs(self.cfg, 1, self.slots.ctx_len),
+            is_leaf=lambda x: isinstance(x, Spec))
 
         def admit(state, pcache, slot, tok0, pos0, rem0, key):
-            def wr(c, p):
-                if c.ndim == p.ndim + 1:      # per-slot positions row
-                    return jax.lax.dynamic_update_slice(
-                        c, p[None].astype(c.dtype), (slot, 0))
-                # every other leaf: (layers, batch=n_slots, ...) ← batch-1 row
-                start = (0, slot) + (0,) * (c.ndim - 2)
+            def wr(c, p, ax):
+                # the batch-1 row lands at ``slot`` on the batch axis
+                start = (0,) * ax + (slot,) + (0,) * (c.ndim - ax - 1)
                 return jax.lax.dynamic_update_slice(c, p.astype(c.dtype),
                                                     start)
 
             return {
-                "cache": jax.tree_util.tree_map(wr, state["cache"], pcache),
+                "cache": jax.tree_util.tree_map(wr, state["cache"], pcache,
+                                                batch_axis),
                 "toks": state["toks"].at[slot].set(tok0),
                 "pos": state["pos"].at[slot].set(pos0),
                 "active": state["active"].at[slot].set(rem0 > 0),

@@ -133,19 +133,14 @@ def decode_attention(q, k_cache, v_cache, cache_positions, pos, k_new, v_new,
     scores are q·k · ``scale`` (default 1/√D).
 
     q: (B,1,H,D); caches: (B,W,KV·D), the heads folded into the minor dim;
-    cache_positions: (W,) int32 holding the absolute position stored in
-    each slot (−1 = empty); pos: scalar int32 of the current token;
-    k_new/v_new: (B,1,KV,D), the current token's own k/v, which is not in
-    the cache: its score joins the ring's in one softmax (a max/sum merge).
-    ``slot`` (= pos mod W) is the ring row the step overwrites afterwards;
-    it holds position pos − W or nothing and is masked, so attention covers
-    exactly the last W positions.
-
-    Ragged (slot-server) variant: ``pos`` and ``slot`` are (B,) and
-    ``cache_positions`` is (B, W) — each batch row decodes at its own
-    absolute position, so the validity mask is per-row.  Both variants run
-    the same op sequence (the scalar bias broadcasts), so lock-step and
-    slot decoding agree bit for bit.
+    cache_positions: (B,W) int32 holding the absolute position stored in
+    each row's slot (−1 = empty); pos: (B,) int32, each row's current
+    position, so the validity mask is per row; k_new/v_new: (B,1,KV,D), the
+    current token's own k/v, which is not in the cache: its score joins the
+    ring's in one softmax (a max/sum merge).  ``slot`` (B,) (= pos mod W) is
+    the ring row the step overwrites afterwards; it holds position pos − W
+    or nothing and is masked, so attention covers exactly the last W
+    positions.
 
     Each query head sits in its KV head's D lanes of the folded dim, zeros
     elsewhere, so one contraction over KV·D scores every head and reads the
@@ -161,18 +156,11 @@ def decode_attention(q, k_cache, v_cache, cache_positions, pos, k_new, v_new,
     from ..distributed.sharding import shard_activation
 
     W = k_cache.shape[1]
-    if jnp.ndim(pos) == 1:                            # ragged: per-row pos
-        valid = (cache_positions >= 0) & (cache_positions <= pos[:, None])
-        valid &= jnp.arange(W)[None, :] != slot[:, None]
-        if window is not None:
-            valid &= cache_positions > (pos[:, None] - window)
-        bias = jnp.where(valid, 0.0, NEG_INF).astype(F32)[:, None]  # (B,1=Sq,W)
-    else:
-        valid = (cache_positions >= 0) & (cache_positions <= pos)
-        valid &= jnp.arange(W) != slot
-        if window is not None:
-            valid &= cache_positions > pos - window
-        bias = jnp.where(valid, 0.0, NEG_INF).astype(F32)[None, None]  # (1,1=Sq,W)
+    valid = (cache_positions >= 0) & (cache_positions <= pos[:, None])
+    valid &= jnp.arange(W)[None, :] != slot[:, None]
+    if window is not None:
+        valid &= cache_positions > (pos[:, None] - window)
+    bias = jnp.where(valid, 0.0, NEG_INF).astype(F32)[:, None]  # (B,1=Sq,W)
 
     B, Sq, H, D = q.shape
     KV = k_new.shape[2]
@@ -182,7 +170,7 @@ def decode_attention(q, k_cache, v_cache, cache_positions, pos, k_new, v_new,
     qx = (qr[..., None, :] * eye).reshape(B, Sq, KV, G, KV * D)
     scores = _scaled(jnp.einsum("bqkgc,bsc->bkgqs", qx, k_cache,
                                 preferred_element_type=F32), D, scale)
-    scores = scores + bias[:, None, None]             # (B|1,1,1,Sq,W)
+    scores = scores + bias[:, None, None]             # (B,1,1,Sq,W)
     scores = shard_activation(scores, ("batch", None, None, None, "ctx"))
     own = _scaled(jnp.einsum("bqkgd,bqkd->bkgq", qr, k_new,
                              preferred_element_type=F32)[..., None], D, scale)

@@ -545,16 +545,17 @@ def loss_fn(cfg: ArchConfig, params, batch, example_weights=None,
 
 def _ring_from_seq(k_seq, v_seq, W: int):
     """(L,B,S,KV,D) stacked per-layer k/v → ring cache (L,B,W,KV·D) of the
-    last W tokens, placed at slot = pos mod W, plus the positions buffer.
+    last W tokens, placed at slot = pos mod W, plus the (B, W) positions
+    buffer.
 
     The slots are static, so the ring is a pad (S < W) or a rotation
     (S ≥ W) of the last W tokens: no scatter.  Two sibling scatters into
     zeros here crash the TPU compiler's scatter fusion."""
-    S = k_seq.shape[2]
+    B, S = k_seq.shape[1:3]
     take = min(W, S)
     pos = np.arange(S - take, S)
-    positions = np.full((W,), -1, np.int32)
-    positions[pos % W] = pos
+    positions = np.full((B, W), -1, np.int32)
+    positions[:, pos % W] = pos
 
     def ring(x):
         x = x[:, :, S - take:]
@@ -662,14 +663,12 @@ def prefill(cfg: ArchConfig, params, batch, ctx_len: Optional[int] = None):
 # decode (serve_step)
 # ============================================================================
 
-def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int, *,
-                ragged: bool = False) -> dict:
+def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int) -> dict:
     """Cache tree as Specs (shapes + logical axes) — feeds input_specs().
 
-    ``ragged=True`` declares the slot-server cache: the positions buffer
-    grows a per-row batch axis ((batch, W) instead of the shared (W,)) so
-    each slot tracks its own absolute position.  Every other leaf already
-    carries a batch axis and is unchanged.
+    Every leaf carries a batch axis; the (batch, W) positions buffer holds
+    the absolute position in each row's ring slot (−1 = empty), so each
+    row decodes at its own position.
 
     A K/V ring is (layers, batch, W, KV·Dh): the heads fold into one minor
     dim, which the chip tiles with W without padding (a (KV, Dh) = (2, 64)
@@ -682,8 +681,7 @@ def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int, *,
     W = min(cfg.sliding_window or ctx_len, ctx_len)
     KV, Dh, nl = cfg.n_kv_heads, cfg.d_head, cfg.n_layers
     dt = cfg.dtype
-    pos_spec = (Spec((batch, W), ("batch", "ctx"), "zeros", "int32")
-                if ragged else Spec((W,), ("ctx",), "zeros", "int32"))
+    pos_spec = Spec((batch, W), ("batch", "ctx"), "zeros", "int32")
 
     def ring(lyrs):
         return {
@@ -733,10 +731,8 @@ def cache_specs(cfg: ArchConfig, batch: int, ctx_len: int, *,
     return c
 
 
-def init_cache(cfg: ArchConfig, batch: int, ctx_len: int, *,
-               ragged: bool = False) -> dict:
-    tree = init_tree(cache_specs(cfg, batch, ctx_len, ragged=ragged),
-                     jax.random.PRNGKey(0))
+def init_cache(cfg: ArchConfig, batch: int, ctx_len: int) -> dict:
+    tree = init_tree(cache_specs(cfg, batch, ctx_len), jax.random.PRNGKey(0))
     if "positions" in tree:
         tree["positions"] = tree["positions"] - 1   # −1 = empty slot
     return tree
@@ -746,11 +742,8 @@ def _decode_attn(cfg, p, h, kc, vc, cache_positions, pos, window, slot):
     """One-token attention over the ring as it stands plus the token's own
     k/v; returns (h', k, v) with the token's (B, KV·Dh) rows, which
     ``_write_rows`` puts at ``slot`` once every layer has attended.
-
-    ``pos``/``slot`` scalar: lock-step decoding (all rows share one
-    position).  ``pos``/``slot`` (B,): ragged decoding — each row carries
-    its own position and ring slot, and ``cache_positions`` is the per-row
-    (B, W) buffer.
+    ``pos``/``slot`` are (B,): each row's position and ring slot, against
+    the (B, W) ``cache_positions``.
     """
     x = L.rms_norm(h, p["norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -762,8 +755,7 @@ def _decode_attn(cfg, p, h, kc, vc, cache_positions, pos, window, slot):
         q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.rope:
-        ragged = jnp.ndim(pos) == 1
-        posv = pos[:, None] if ragged else jnp.full((1,), pos)
+        posv = pos[:, None]
         q = L.rope(q, posv, cfg.rope_theta)
         k = L.rope(k, posv, cfg.rope_theta)
     o = L.decode_attention(q, kc, vc, cache_positions, pos, k, v, slot,
@@ -775,21 +767,18 @@ def _decode_attn(cfg, p, h, kc, vc, cache_positions, pos, window, slot):
 
 def _write_rows(ring, k, v, slot):
     """Write one step's rows into a ring {"k", "v"} of (layers, B, W, KV·Dh):
-    ``k``/``v`` (layers, B, KV·Dh) land at ring slot ``slot`` (scalar, or
-    (B,) per row).  One update per ring for all layers, in place in a
-    donated or scan-carried ring.  The ragged write scatters single
-    KV·Dh-wide rows, indexed by (layer, row, slot): a scatter whose window
-    spans the layers would ask for a layout with the layers minor, and the
-    ring would then be relayouted for the reads at every step."""
+    ``k``/``v`` (layers, B, KV·Dh) land at each row's ring slot ``slot``
+    (B,).  One update per ring for all layers, in place in a donated or
+    scan-carried ring.  The write scatters single KV·Dh-wide rows, indexed
+    by (layer, row, slot): a scatter whose window spans the layers would
+    ask for a layout with the layers minor, and the ring would then be
+    relayouted for the reads at every step."""
     def put(c, new):
-        if jnp.ndim(slot) == 1:
-            nl, B = c.shape[:2]
-            li = jnp.broadcast_to(jnp.arange(nl)[:, None], (nl, B))
-            bi = jnp.broadcast_to(jnp.arange(B)[None, :], (nl, B))
-            si = jnp.broadcast_to(slot[None, :], (nl, B))
-            return c.at[li, bi, si].set(new)
-        return jax.lax.dynamic_update_slice_in_dim(c, new[:, :, None], slot,
-                                                   axis=2)
+        nl, B = c.shape[:2]
+        li = jnp.broadcast_to(jnp.arange(nl)[:, None], (nl, B))
+        bi = jnp.broadcast_to(jnp.arange(B)[None, :], (nl, B))
+        si = jnp.broadcast_to(slot[None, :], (nl, B))
+        return c.at[li, bi, si].set(new)
 
     return {"k": put(ring["k"], k), "v": put(ring["v"], v)}
 
@@ -824,11 +813,8 @@ def _decode_mamba(cfg, p, h, conv_state, ssd_state):
 def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
     """serve_step: ONE new token per sequence against the cache.
 
-    tokens: (B,) int32; pos: scalar int32 (current absolute position) for
-    lock-step decoding, or (B,) int32 per-row positions for ragged
-    (slot-server) decoding against a cache built with
-    ``cache_specs(..., ragged=True)`` — the positions buffer is then
-    (B, W) and every row writes its own ring slot.
+    tokens: (B,) int32; pos: (B,) int32, each row's absolute position (a
+    scalar is broadcast to every row); every row writes its own ring slot.
     The layer loop only reads the K/V rings; it returns the step's new rows,
     which one ``_write_rows`` per ring stores after the loop.  The recurrent
     state stacks ride in the loop's carry and each Mamba layer updates its
@@ -837,20 +823,15 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, ctx_len: int):
     """
     W = min(cfg.sliding_window or ctx_len, ctx_len)
     window = cfg.sliding_window
-    ragged = jnp.ndim(pos) == 1
+    pos = jnp.broadcast_to(pos, tokens.shape)
     slot = jnp.mod(pos, W)
     h = _embed(cfg, params, tokens[:, None])          # (B,1,d)
     cache = dict(cache)
 
     if "positions" in cache:
-        if ragged:
-            rows = jnp.arange(tokens.shape[0])
-            cache["positions"] = cache["positions"].at[rows, slot].set(
-                pos.astype(cache["positions"].dtype))
-        else:
-            cache["positions"] = jax.lax.dynamic_update_index_in_dim(
-                cache["positions"], pos.astype(cache["positions"].dtype),
-                slot, axis=0)
+        rows = jnp.arange(tokens.shape[0])
+        cache["positions"] = cache["positions"].at[rows, slot].set(
+            pos.astype(cache["positions"].dtype))
         cpos = cache["positions"]
 
     fam = cfg.family

@@ -3,10 +3,10 @@
 The repo's compiled drivers (``PlanExecutor``, ``SlotServer``) live and
 die by ONE rule: the jitted programs are cached on the instance and must
 never re-trace once warm — a silent retrace turns a 5.6× dispatch win
-into a recompile-per-run regression (found twice already: the fresh-
-closure tiler in PR 5, the fresh ``jax.jit`` per ``Server.generate`` in
-PR 7).  :class:`CompileWatch` generalises the ``SlotServer.compile_counts``
-gate those PRs hand-rolled:
+into a recompile-per-run regression (found twice already: a tiler that
+built a fresh closure per call, and a decode loop that built a fresh
+``jax.jit`` per call).  :class:`CompileWatch` generalises the
+``SlotServer.compile_counts`` gate those fixes hand-rolled:
 
 * :meth:`wrap` wraps any cached jit; after each call the traced-signature
   count (``fn._cache_size()``) is compared to the last seen value and

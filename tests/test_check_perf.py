@@ -4,7 +4,7 @@ The regression under test: a baseline row carrying ``grid_speedup`` whose
 *current* row lacks the field used to read ``cur.get("grid_speedup",
 0.0)`` and fail with a bogus ``0.000 < floor`` REGRESSION verdict — the
 failure message must say the FIELD is missing, not that throughput
-dropped to zero.  Plus the ``serve_slots`` kind's compare path and the
+dropped to zero.  Plus the other kinds' compare paths and the
 kind-dispatch rules.
 """
 import importlib.util
@@ -32,14 +32,6 @@ def _runtime_payload(*, grid_speedup=None, rounds_per_s=100.0):
             "entries": [{"runtime": "eager", "metrics": "chunk",
                          "rounds_per_launch": 1, "rounds_per_s": 50.0},
                         entry]}
-
-
-def _serve_payload(*, tok_per_s=40.0, occupancy=0.9, lock=100.0):
-    return {"bench": "serve_slots",
-            "entries": [{"mode": "lockstep", "tok_per_s": lock},
-                        {"mode": "rotating", "n_slots": 2,
-                         "admission": "pure", "tok_per_s": tok_per_s,
-                         "occupancy": occupancy}]}
 
 
 # ---------------------------------------------------------------------------
@@ -71,38 +63,6 @@ def test_rows_returns_rows_and_eager_tuple():
     rows, eager = check_perf._rows(_runtime_payload())
     assert eager == 50.0
     assert ("scan", "chunk", 8) in rows
-
-
-# ---------------------------------------------------------------------------
-# the serve_slots kind
-# ---------------------------------------------------------------------------
-
-def test_serve_kind_passes_identical_payloads():
-    assert check_perf.check_serve(_serve_payload(), _serve_payload(),
-                                  tolerance=0.3) == []
-
-
-def test_serve_kind_normalises_by_lockstep_row():
-    base = _serve_payload(tok_per_s=40.0, lock=100.0)
-    # half the absolute speed but the same RATIO: a slower machine, not a
-    # regression
-    cur = _serve_payload(tok_per_s=20.0, lock=50.0)
-    assert check_perf.check_serve(cur, base, tolerance=0.3) == []
-    # ratio collapse IS a regression
-    bad = _serve_payload(tok_per_s=10.0, lock=100.0)
-    fails = check_perf.check_serve(bad, base, tolerance=0.3)
-    assert len(fails) == 1 and "tok/s" in fails[0]
-
-
-def test_serve_kind_gates_occupancy_and_missing_fields():
-    base = _serve_payload(occupancy=0.9)
-    fails = check_perf.check_serve(_serve_payload(occupancy=0.3), base,
-                                   tolerance=0.3)
-    assert len(fails) == 1 and "occupancy" in fails[0]
-    cur = _serve_payload()
-    del cur["entries"][1]["occupancy"]
-    fails = check_perf.check_serve(cur, base, tolerance=0.3)
-    assert len(fails) == 1 and "lacks the field" in fails[0]
 
 
 # ---------------------------------------------------------------------------
@@ -167,8 +127,6 @@ def test_missing_row_failure_names_both_files():
 def test_rows_without_eager_names_the_file():
     with pytest.raises(SystemExit, match="weird.json"):
         check_perf._rows({"entries": []}, "weird.json")
-    with pytest.raises(SystemExit, match="weird.json"):
-        check_perf._serve_rows({"entries": []}, "weird.json")
 
 
 def test_faults_kind_missing_ratio_is_clean_failure_not_keyerror():
@@ -228,21 +186,15 @@ def _run_main(tmp_path, cur, base, extra=()):
         capture_output=True, text=True)
 
 
-def test_main_accepts_serve_payload(tmp_path):
-    r = _run_main(tmp_path, _serve_payload(), _serve_payload())
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "no dispatch-layer regression" in r.stdout
-
-
 def test_main_skips_unknown_kind(tmp_path):
     r = _run_main(tmp_path, {"bench": "scenarios", "entries": []},
-                  _serve_payload())
+                  _runtime_payload())
     assert r.returncode == 0
     assert "SKIP" in r.stdout
 
 
 def test_main_rejects_kind_mismatch(tmp_path):
-    r = _run_main(tmp_path, _serve_payload(), _runtime_payload())
+    r = _run_main(tmp_path, _obs_payload(), _runtime_payload())
     assert r.returncode != 0
     out = r.stdout + r.stderr
     assert "mismatch" in out
@@ -256,12 +208,5 @@ def test_main_accepts_obs_payload(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     r = _run_main(tmp_path, _obs_payload(ratio=0.5), _obs_payload(),
                   extra=("--tolerance", "0.05"))
-    assert r.returncode == 1
-    assert "PERF REGRESSION" in r.stdout
-
-
-def test_main_fails_on_serve_regression(tmp_path):
-    r = _run_main(tmp_path, _serve_payload(tok_per_s=10.0),
-                  _serve_payload(tok_per_s=40.0))
     assert r.returncode == 1
     assert "PERF REGRESSION" in r.stdout

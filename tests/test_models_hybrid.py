@@ -53,9 +53,9 @@ def test_decode_updates_each_layers_state_in_the_stack():
     toks = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0, cfg.vocab)
     rows = [prefill(cfg, params, {"tokens": toks[b:b + 1, :n]},
                     ctx_len=ctx)[1] for b, n in enumerate(lens)]
-    cache = jax.tree_util.tree_map(
-        lambda *a: jnp.stack(a) if a[0].ndim == 1 else
-        jnp.concatenate(a, axis=1), *rows)
+    cache = jax.tree_util.tree_map_with_path(
+        lambda path, *a: jnp.concatenate(
+            a, axis=0 if path[0].key == "positions" else 1), *rows)
     assert set(cache) == {"self", "positions", "ssm"}
     assert cache["ssm"]["ssd"].shape[0] == 9
     assert cache["self"]["k"].shape[0] == 1

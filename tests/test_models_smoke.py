@@ -212,19 +212,17 @@ def _oracle_attn(cfg, p, h, kc, vc, cpos, pos, window, slot):
     if cfg.qk_norm:
         q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    ragged = jnp.ndim(pos) == 1
-    posv = pos[:, None] if ragged else jnp.full((1,), pos)
+    posv = pos[:, None]
     q, k = L.rope(q, posv, cfg.rope_theta), L.rope(k, posv, cfg.rope_theta)
     B, W = kc.shape[:2]
-    rows = jnp.arange(B) if ragged else slice(None)
+    rows = jnp.arange(B)
     kc = kc.at[rows, slot].set(k.reshape(B, -1))
     vc = vc.at[rows, slot].set(v.reshape(B, -1))
-    pos_b = pos[:, None] if ragged else pos
-    valid = (cpos >= 0) & (cpos <= pos_b)
+    valid = (cpos >= 0) & (cpos <= posv)
     if window is not None:
-        valid &= cpos > pos_b - window
+        valid &= cpos > posv - window
     bias = jnp.where(valid, 0.0, L.NEG_INF).astype(jnp.float32)
-    bias = bias[:, None, None, None] if ragged else bias
+    bias = bias[:, None, None, None]
     KV, Dh = k.shape[2:]
     qr = q.reshape(B, 1, KV, -1, Dh)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qr, kc.reshape(B, W, KV, Dh),
@@ -240,12 +238,11 @@ def _oracle_decode_step(cfg, params, cache, tokens, pos, ctx_len):
     """Write-then-attend decode, layer by layer in Python (dense, hybrid
     and audio families)."""
     W = min(cfg.sliding_window or ctx_len, ctx_len)
+    pos = jnp.broadcast_to(pos, tokens.shape)
     window, slot = cfg.sliding_window, jnp.mod(pos, W)
-    ragged = jnp.ndim(pos) == 1
     cache = jax.tree_util.tree_map(lambda a: a, cache)
-    rows = jnp.arange(tokens.shape[0]) if ragged else slice(None)
-    cache["positions"] = cache["positions"].at[
-        (rows, slot) if ragged else slot].set(pos)
+    rows = jnp.arange(tokens.shape[0])
+    cache["positions"] = cache["positions"].at[rows, slot].set(pos)
     cpos = cache["positions"]
     h = M._embed(cfg, params, tokens[:, None])
     ring = cache["attn"] if cfg.family == "hybrid" else cache["self"]
@@ -276,9 +273,18 @@ def _oracle_decode_step(cfg, params, cache, tokens, pos, ctx_len):
     return M._unembed(cfg, params, h)[:, 0], cache
 
 
+def _stack_rows(rows):
+    """Batch-1 prefill caches stacked into one cache of len(rows) rows: the
+    positions buffer is (batch, W), every other leaf (layers, batch, ...)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, *a: jnp.concatenate(
+            a, axis=0 if path[0].key == "positions" else 1), *rows)
+
+
 def _prefilled(cfg, params, prompt_lens, ctx, ragged):
-    """A cache after prefill: one batch for the lock-step path, or per-row
-    prefills of different lengths stacked for the ragged path."""
+    """A cache after prefill: one batch of equal prompts for a scalar
+    ``pos``, or per-row prefills of different lengths stacked for per-row
+    ``pos``."""
     from repro.models import prefill
     B = len(prompt_lens)
     batch = _batch(cfg, B=B, S=max(prompt_lens))
@@ -286,11 +292,9 @@ def _prefilled(cfg, params, prompt_lens, ctx, ragged):
         S = prompt_lens[0]
         batch = {k: (v[:, :S] if k == "tokens" else v) for k, v in batch.items()}
         return prefill(cfg, params, batch, ctx_len=ctx)[1]
-    rows = [prefill(cfg, params, {"tokens": batch["tokens"][b:b + 1, :n]},
-                    ctx_len=ctx)[1] for b, n in enumerate(prompt_lens)]
-    return jax.tree_util.tree_map(
-        lambda *a: (jnp.stack(a) if a[0].ndim == 1 else
-                    jnp.concatenate(a, axis=1)), *rows)
+    return _stack_rows([
+        prefill(cfg, params, {"tokens": batch["tokens"][b:b + 1, :n]},
+                ctx_len=ctx)[1] for b, n in enumerate(prompt_lens)])
 
 
 @pytest.mark.parametrize("name,ragged,window", [
@@ -307,7 +311,8 @@ def test_decode_step_matches_write_then_attend(name, ragged, window):
     of writing first and attending over the whole ring, and the same
     cache: the written rows, and every other ring row untouched.  With a
     4-row window the ring wraps, so the row being overwritten (an old
-    position) must stay out of the softmax."""
+    position) must stay out of the softmax.  ``ragged`` passes per-row
+    ``pos`` (rows at different positions); otherwise one scalar ``pos``."""
     cfg = get_arch(name).reduced().with_(remat="none", sliding_window=window,
                                          dtype="float32")
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -343,3 +348,31 @@ def test_decode_step_matches_write_then_attend(name, ragged, window):
         cache, pos = got, pos + 1
     if window is not None:        # the ring wrapped before the first step
         assert min(lens) > W
+
+
+@pytest.mark.parametrize("name", ARCH_NAMES)
+def test_decode_step_scalar_pos_is_per_row_pos_broadcast(name):
+    """A scalar ``pos`` is the same step as that position given to every
+    row: the same logits and the same cache, bit for bit."""
+    from repro.models import prefill
+    cfg = get_arch(name).reduced().with_(remat="none")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    B, ctx = 2, 16
+    batch = _batch(cfg, B=B, S=8)
+    _, cache = prefill(cfg, params, batch, ctx_len=ctx)
+    step = jax.jit(lambda c, t, p: decode_step(cfg, params, c, t, p, ctx))
+    tok = jnp.array([3, 5], jnp.int32)
+    pos = batch["tokens"].shape[1]
+    a_cache = b_cache = cache
+    for t in range(2):
+        a, a_cache = step(a_cache, tok, jnp.int32(pos + t))
+        b, b_cache = step(b_cache, tok, jnp.full((B,), pos + t, jnp.int32))
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+        for x, y in zip(jax.tree_util.tree_leaves(a_cache),
+                        jax.tree_util.tree_leaves(b_cache)):
+            np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                          np.asarray(y, np.float32))
+    if "positions" in cache:
+        assert cache["positions"].shape == (B, min(cfg.sliding_window or ctx,
+                                                   ctx))

@@ -379,10 +379,9 @@ def test_graceful_drain():
 def test_serve_job_resilience_fields_and_backend_surface():
     with pytest.raises(ValueError, match="max_retries"):
         ServeJob(max_retries=0)
-    with pytest.raises(ValueError, match="max_retries"):
-        ServeJob(max_retries=2)                    # needs the slot lane
-    with pytest.raises(ValueError, match="queue_cap"):
-        ServeJob(queue_cap=4)
+    # every job runs on the slot lane: no n_slots needed
+    assert ServeJob(max_retries=2).max_retries == 2
+    assert ServeJob(queue_cap=4).queue_cap == 4
     with pytest.raises(ValueError, match="queue_cap"):
         ServeJob(queue_cap=0, n_slots=2)
     with pytest.raises(ValueError, match="shed policy"):

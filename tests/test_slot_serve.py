@@ -3,8 +3,8 @@
 The two acceptance gates live here:
 
 * **parity** — all slots filled, no arrivals, greedy: the slot lane must
-  reproduce lock-step ``Server.generate`` token-for-token (the lock-step
-  driver is the oracle; the ragged decode path is a strict superset).
+  reproduce a plain loop of ``M.prefill`` and ``M.decode_step``
+  token-for-token, for one arch of each family the slot server accepts.
 * **no retrace on admission** — requests rotating through freed slots
   must leave the chunk/admit compile counts at one trace per program
   (the whole point of masking over control flow).
@@ -13,6 +13,9 @@ Plus the admission layer as a unit: policy parsing, arrival draws, the
 scheduler-registry remap, the trace → ``Schedule`` lowering, and the
 ordered-tap streaming contract.
 """
+import importlib.util
+from pathlib import Path
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -22,10 +25,9 @@ from jax.sharding import Mesh
 from repro.api import ExperimentSpec, ServeJob, run
 from repro.api.backends import ServeBackend
 from repro.configs import get_arch
-from repro.distributed import (AdmissionPolicy, AdmissionTrace, Server,
-                               ServeConfig, SlotConfig, SlotServer,
-                               draw_arrivals, parse_admission)
-from repro.models import init_params, model as M
+from repro.distributed import (AdmissionPolicy, AdmissionTrace, SlotConfig,
+                               SlotServer, draw_arrivals, parse_admission)
+from repro.models import init_params
 from repro.scenarios import tau_report
 
 TINY = dict(n_layers=1, d_model=8, n_heads=1, n_kv_heads=1, d_ff=16,
@@ -48,30 +50,40 @@ def _prompts(n, plen, vocab, seed=0):
         0, vocab, (n, plen)).astype(np.int32)
 
 
-def _lockstep_tokens(cfg, params, prompts, T, ctx, temperature=0.0):
-    """The oracle: eager prefill + lock-step generate (backend flow)."""
-    srv = Server(cfg, _mesh(), ServeConfig(batch=prompts.shape[0],
-                                           ctx_len=ctx,
-                                           temperature=temperature))
-    logits, cache = M.prefill(cfg, params, {"tokens": jnp.asarray(prompts)},
-                              ctx_len=ctx)
-    tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    gen = srv.generate(params, np.asarray(tok0), T - 1,
-                       start_pos=prompts.shape[1], cache=cache)
-    return np.concatenate([np.asarray(tok0)[:, None], gen], axis=1)
+def _plain_loop_tokens(cfg, params, prompts, T, ctx):
+    """The oracle, with no serving engine: ``chip_smoke.plain_loop_tokens``
+    (each prompt prefilled alone, as the slot server admits it, so MoE
+    routing sees the same rows; then T − 1 greedy ``M.decode_step`` calls
+    over every row at once)."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    return smoke.plain_loop_tokens(cfg, params, prompts, T, ctx)
 
 
 # ---------------------------------------------------------------------------
 # acceptance gates
 # ---------------------------------------------------------------------------
 
-def test_slot_lane_parity_bit_for_bit():
-    """Full static batch, no arrivals, greedy ⇒ identical to lock-step."""
-    cfg, params = _setup()
+@pytest.mark.parametrize("arch,tiny", [
+    ("qwen2-0.5b", TINY),
+    ("mamba2-370m", {}),
+    ("granite-4.0-h-micro", {}),
+    ("zamba2-7b", {}),
+    ("deepseek-moe-16b", {}),
+])
+def test_slot_lane_parity_bit_for_bit(arch, tiny):
+    """Full static batch, no arrivals, greedy ⇒ identical to the plain
+    prefill + decode loop, for one arch of each family the slot server
+    serves (dense, SSM, layer-pattern hybrid, shared-attention hybrid,
+    MoE)."""
+    cfg = get_arch(arch).reduced().with_(remat="none", **tiny)
+    params = init_params(cfg, jax.random.PRNGKey(0))
     B, plen, T = 3, 5, 6
     ctx = plen + T
     prompts = _prompts(B, plen, cfg.vocab)
-    ref = _lockstep_tokens(cfg, params, prompts, T, ctx)
+    ref = _plain_loop_tokens(cfg, params, prompts, T, ctx)
     srv = SlotServer(cfg, _mesh(), SlotConfig(n_slots=B, ctx_len=ctx,
                                               steps_per_launch=2))
     res = srv.serve(params, prompts, T)
@@ -170,7 +182,7 @@ def test_single_token_budget_completes_at_admission():
     res = srv.serve(params, prompts, 1)
     assert res.tokens.shape == (3, 1)
     assert res.chunks == 0 and res.tap_rows == 0
-    ref = _lockstep_tokens(cfg, params, prompts, 1, 16)[:, :1]
+    ref = _plain_loop_tokens(cfg, params, prompts, 1, 16)
     np.testing.assert_array_equal(ref, res.tokens)
 
 
@@ -189,14 +201,21 @@ def test_slot_server_rejects_budget_overflow_and_bad_families():
 # ---------------------------------------------------------------------------
 
 def test_backend_slot_route_matches_lockstep_route():
-    """n_slots == batch, no arrivals ⇒ the two ServeBackend routes emit
-    identical token matrices (same prompt stream by construction)."""
-    lock = run(ExperimentSpec(objective=ServeJob(
-        batch=3, prompt_len=5, arch_overrides=TINY_OVR), T=6))
+    """A job without ``n_slots`` serves ``batch`` requests through
+    ``batch`` slots: the same tokens as ``n_slots == batch`` set
+    explicitly, and as the plain prefill + decode loop on the job's own
+    prompt stream and weights."""
+    job = ServeJob(batch=3, prompt_len=5, arch_overrides=TINY_OVR)
+    lock = run(ExperimentSpec(objective=job, T=6))
     slot = run(ExperimentSpec(objective=ServeJob(
         batch=3, prompt_len=5, arch_overrides=TINY_OVR, n_slots=3,
         steps_per_launch=2), T=6))
+    assert lock.extra["n_slots"] == 3 and lock.x.shape == (3, 6)
     np.testing.assert_array_equal(lock.x, slot.x)
+    cfg = job.make_arch()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    ref = _plain_loop_tokens(cfg, params, lock.extra["prompts"], 6, 5 + 6)
+    np.testing.assert_array_equal(ref, lock.x)
     assert slot.backend == "serve"
     assert slot.schedule is not None
     assert slot.extra["tau_report"]["global"]["tau_c"] <= 3 + 1
@@ -322,8 +341,8 @@ def test_deadline_times_out_queued_requests():
 def test_serve_job_deadline_validation_and_backend_surface():
     with pytest.raises(ValueError, match="deadline"):
         ServeJob(deadline=-1, n_slots=2)
-    with pytest.raises(ValueError, match="deadline"):
-        ServeJob(deadline=4)                      # needs the slot lane
+    # every job runs on the slot lane: no n_slots needed
+    assert ServeJob(deadline=4).deadline == 4
     res = ServeBackend(mesh=_mesh()).run(ExperimentSpec(
         objective=ServeJob(batch=2, prompt_len=5, arch_overrides=TINY_OVR,
                            n_slots=1, n_requests=3, deadline=1,
